@@ -1,8 +1,8 @@
 """Sweep-executor throughput benchmark -> `BENCH_sweep.json`.
 
-Times `run_sweep` under the serial, process, vectorized and (when jax
-is importable) jax executors on fixed 60-case suites (all executors
-produce identical results — only wall-clock differs) and writes
+Times `run_sweep` under the serial, process, vectorized and jax
+executors on fixed 60-case suites (all executors produce identical
+results — only wall-clock differs) and writes
 cases/sec plus speedups-vs-serial to `BENCH_sweep.json` in the working
 directory, so the sweep-throughput trajectory is tracked per PR. The
 `jax` rows time the jit device steppers (`repro.core.engine.jax_stepper`)
@@ -52,14 +52,7 @@ REPEATS = int(os.environ.get("REPRO_BENCH_SWEEP_REPEATS", "3"))
 OUT_PATH = "BENCH_sweep.json"
 
 
-def _executors() -> tuple[str, ...]:
-    from repro.core.engine import jax_available
-
-    base = ("serial", "process", "vectorized")
-    return base + ("jax",) if jax_available() else base
-
-
-EXECUTORS = _executors()
+EXECUTORS = ("serial", "process", "vectorized", "jax")
 
 
 def stress_suite(num_cases: int = CASES) -> TraceSuite:

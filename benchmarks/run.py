@@ -13,6 +13,11 @@ import time
 
 
 def main() -> None:
+    from pathlib import Path
+
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache(Path(__file__).resolve().parent.parent)
     from benchmarks import (bench_ablation, bench_aliyun, bench_dataplane,
                             bench_fig8, bench_fig9, bench_fig10, bench_fig11,
                             bench_kernels, bench_sweep, bench_table2)

@@ -6,15 +6,14 @@
 2. times the numpy vectorized executor against the jax executor on an
    execution-bound trace-frozen suite — the jax rows include compile
    time on the first run; the point of the backend is that the same
-   compiled programs run unchanged on an accelerator,
-3. shows the graceful degradation story: batches the device engine
-   cannot take fall back to the numpy steppers with identical results.
+   compiled programs run unchanged on an accelerator.
 
     PYTHONPATH=src python examples/jax_sweep.py
 """
 import time
 
-from repro.core.engine import jax_available
+import jax
+
 from repro.sim import MonteCarloSuite, SampleSpace, TraceSuite, run_sweep
 
 
@@ -56,13 +55,10 @@ def jax_throughput():
     print(f"\nexecution-bound 24-case suite (warm): "
           f"numpy vectorized {timings['vectorized'] * 1e3:.0f}ms, "
           f"jax {timings['jax'] * 1e3:.0f}ms on "
-          f"{'a CPU device' if jax_available() else 'numpy fallback'}")
+          f"{jax.devices()[0].platform}")
 
 
 def main():
-    if not jax_available():
-        print("jax is not installed: executor='jax' will warn and fall "
-              "back to the numpy vectorized engine (results identical).")
     worst = jax_parity()
     assert worst < 1e-6, "jax executor must match the reference engine"
     jax_throughput()

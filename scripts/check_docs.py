@@ -2,7 +2,9 @@
 
 1. Executes every ```python code block in README.md and docs/*.md top to
    bottom (one shared namespace per file), so the quickstarts and the
-   engine-guide walkthroughs keep running exactly as written.
+   engine-guide walkthroughs keep running exactly as written. The blocks
+   run in a child process (`--snippets`), so this parent never imports
+   JAX: on a TPU host a parent holding the chip would starve every child.
 2. Verifies that every repo path (src/..., benchmarks/..., examples/...,
    tests/..., docs/...) referenced in README.md and docs/*.md exists.
 3. Verifies that every dotted `repro.*` module reference resolves to a
@@ -14,6 +16,7 @@ Run from the repo root (CI does):  python scripts/check_docs.py
 """
 from __future__ import annotations
 
+import os
 import re
 import subprocess
 import sys
@@ -68,8 +71,33 @@ def check_modules() -> list[str]:
     return errors
 
 
+def _child_env() -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(SRC) + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    return env
+
+
 def run_doc_snippets() -> list[str]:
-    sys.path.insert(0, str(SRC))
+    """Execute the doc snippets in a child process; its errors come back
+    one per line on stdout, prefixed `ERROR: `."""
+    print("-- executing doc snippets in a child process")
+    try:
+        proc = subprocess.run([sys.executable, str(Path(__file__).resolve()),
+                               "--snippets"], env=_child_env(), cwd=ROOT,
+                              capture_output=True, text=True, timeout=600)
+    except subprocess.TimeoutExpired:
+        return ["doc snippets timed out after 600s"]
+    sys.stdout.write(proc.stdout)
+    errors = [line[len("ERROR: "):] for line in proc.stdout.splitlines()
+              if line.startswith("ERROR: ")]
+    if proc.returncode != 0 and not errors:
+        errors.append(f"snippet runner exited {proc.returncode}: "
+                      f"{proc.stderr.strip()[-400:]}")
+    return errors
+
+
+def _exec_doc_snippets() -> list[str]:
     errors = []
     for doc in doc_files():
         text = doc.read_text()
@@ -90,12 +118,8 @@ EXAMPLE_SCRIPTS = ("examples/jax_sweep.py",)
 
 
 def run_example_scripts() -> list[str]:
-    import os
-
     errors = []
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(SRC) + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env = _child_env()
     for rel in EXAMPLE_SCRIPTS:
         print(f"-- running {rel}")
         try:
@@ -112,6 +136,11 @@ def run_example_scripts() -> list[str]:
 
 
 def main() -> int:
+    if sys.argv[1:] == ["--snippets"]:
+        errors = _exec_doc_snippets()
+        for e in errors:
+            print(f"ERROR: {e!s}".replace("\n", " "))
+        return 1 if errors else 0
     errors = check_paths() + check_modules()
     errors += run_doc_snippets()
     errors += run_example_scripts()

@@ -50,7 +50,6 @@ __all__ = [
     "execute_pipeline_batch",
     "execute_round_batch",
     "run_scheme_vectorized",
-    "jax_available",
     "BatchExecutionResult",
     "execute_plans_batch",
     "identity_block_map",
@@ -59,9 +58,7 @@ __all__ = [
 
 _VECTORIZED = ("execute_pipeline_batch", "execute_round_batch",
                "run_scheme_vectorized")
-_JAX = ("jax_available",)
-# the byte data plane imports jax via repro.kernels — lazy like the
-# jax stepper, so numpy-only sweep workers stay cheap to spawn
+# the byte data plane imports jax via repro.kernels — loaded lazily
 _DATAPLANE = ("BatchExecutionResult", "execute_plans_batch",
               "identity_block_map")
 
@@ -71,10 +68,6 @@ def __getattr__(name):
         from repro.core.engine import vectorized
 
         return getattr(vectorized, name)
-    if name in _JAX:
-        from repro.core.engine import jax_stepper
-
-        return getattr(jax_stepper, name)
     if name in _DATAPLANE:
         from repro.core.engine import dataplane
 

@@ -57,7 +57,7 @@ the cluster size N is the only raw shape dimension. Buffer donation is
 enabled on non-CPU backends only (CPU XLA cannot consume donations and
 would warn on every call).
 
-Everything runs under `jax.experimental.enable_x64` so device floats
+Everything runs under `jax.enable_x64(True)` so device floats
 are the same float64 ops the numpy engine performs; the parity suite
 (`tests/test_jax_engine.py`) pins all 8 schemes x 3 volatility regimes
 to the reference engines at 1e-6 relative tolerance with identical
@@ -88,33 +88,15 @@ class JaxUnsupported(RuntimeError):
     """The batch cannot run on the jax engine (caller falls back)."""
 
 
-_JAX_OK: bool | None = None
-
-
-def jax_available() -> bool:
-    """True when jax imports and can build arrays (checked once)."""
-    global _JAX_OK
-    if _JAX_OK is None:
-        try:
-            import jax  # noqa: F401
-            import jax.numpy as jnp
-
-            jnp.zeros(1)
-            _JAX_OK = True
-        except Exception:  # pragma: no cover - env without a working jax
-            _JAX_OK = False
-    return _JAX_OK
-
-
 def _pow2(n: int) -> int:
     """Smallest power of two >= max(n, 1) — the shape-bucketing unit."""
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
 def _x64():
-    from jax.experimental import enable_x64
+    import jax
 
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 # ------------------------------------------------------------ jitted programs
@@ -126,9 +108,11 @@ def _build_fns() -> types.SimpleNamespace:
     import jax.numpy as jnp
     from jax import lax
 
-    # CPU XLA cannot consume donated buffers (it would warn per call);
-    # on accelerators the per-call hop tensors and t0 are donated.
-    donate = (0, 1, 2, 3) if jax.default_backend() != "cpu" else ()
+    # CPU XLA cannot consume donated buffers (it would warn per call).
+    # On accelerators only t0 is donated, and only where an output of
+    # its shape can take its buffer (the per-round and pipeline end
+    # times): a donation no output can use is refused with a warning.
+    donate_t0 = jax.default_backend() != "cpu"
 
     def epoch_state(t, ctx):
         """(bw, epoch_end, epoch) for every case at its own time `t` —
@@ -212,7 +196,8 @@ def _build_fns() -> types.SimpleNamespace:
         t, hop_i, _, ovf, it = lax.while_loop(cond, body, init)
         return t, ovf, it, done_mask(hop_i).all()
 
-    run_round = jax.jit(round_events, donate_argnums=donate)
+    run_round = jax.jit(round_events,
+                        donate_argnums=(3,) if donate_t0 else ())
 
     def rounds_scan(hop_u, hop_v, n_hops, t0, ctx):
         """All rounds of a batch as one `lax.scan` over the (padded)
@@ -230,7 +215,7 @@ def _build_fns() -> types.SimpleNamespace:
                                            (hop_u, hop_v, n_hops))
         return tends, ovf, mx, ok
 
-    run_rounds = jax.jit(rounds_scan, donate_argnums=donate)
+    run_rounds = jax.jit(rounds_scan)
 
     @functools.lru_cache(maxsize=None)
     def pipeline(dmax: int):
@@ -292,7 +277,7 @@ def _build_fns() -> types.SimpleNamespace:
             return t, ovf, it, ~(left > _EPS * chunk_col).any()
 
         return jax.jit(pipeline_events,
-                       donate_argnums=(3, 4) if donate else ())
+                       donate_argnums=(4,) if donate_t0 else ())
 
     return types.SimpleNamespace(run_round=run_round,
                                  run_rounds=run_rounds,
@@ -327,8 +312,6 @@ class _EngineBase:
 
     def __init__(self, scenarios, num_nodes: int, need: np.ndarray,
                  mmax: int):
-        if not jax_available():
-            raise JaxUnsupported("jax is not importable")
         if any(not sc.ingress.persistent_shares for sc in scenarios):
             # epoch-keyed share redraws cannot be pretabulated
             raise JaxUnsupported("non-persistent ingress shares")
@@ -570,9 +553,7 @@ def _pipeline_fanin(parent, edge_valid,
 # ------------------------------------------------------------------ factories
 def make_round_engine(scenarios, num_nodes: int, arrays):
     """A `JaxRoundEngine` for the batch, or None when it must fall back
-    to the numpy engine (no jax, non-persistent shares, memory cap)."""
-    if not jax_available():
-        return None
+    to the numpy engine (non-persistent shares, memory cap)."""
     try:
         return JaxRoundEngine(scenarios, num_nodes, arrays)
     except JaxUnsupported:
@@ -581,8 +562,6 @@ def make_round_engine(scenarios, num_nodes: int, arrays):
 
 def make_pipeline_engine(scenarios, num_nodes: int, parent, edge_valid):
     """A `JaxPipelineEngine` for the batch, or None (numpy fallback)."""
-    if not jax_available():
-        return None
     try:
         return JaxPipelineEngine(scenarios, num_nodes, parent, edge_valid)
     except JaxUnsupported:

@@ -40,7 +40,6 @@ from __future__ import annotations
 import dataclasses
 import math
 import time as _time
-import warnings
 
 import numpy as np
 
@@ -734,23 +733,17 @@ def run_work_vectorized(
     `backend` picks the *execution* stepper: "numpy" (this module's
     masked-array loops) or "jax" (`repro.core.engine.jax_stepper`'s
     jit-compiled device programs — planning and the BMF replan host loop
-    are unchanged). Batches the jax engine cannot take (jax missing,
-    non-persistent ingress shares, epoch stacks past the memory cap)
-    fall back to the numpy steppers; results are backend-independent
-    either way.
+    are unchanged). Without a usable jax, "jax" raises. Batches the jax
+    engine cannot take (non-persistent ingress shares, epoch stacks past
+    the memory cap) fall back to the numpy steppers; results are
+    backend-independent either way.
     """
     round_factory = ppt_factory = None
     if backend == "jax":
         from repro.core.engine import jax_stepper
 
-        if jax_stepper.jax_available():
-            round_factory = jax_stepper.make_round_engine
-            ppt_factory = jax_stepper.make_pipeline_engine
-        else:
-            warnings.warn(
-                "backend='jax': jax is not importable; running the batch "
-                "on the numpy vectorized engine instead",
-                RuntimeWarning, stacklevel=2)
+        round_factory = jax_stepper.make_round_engine
+        ppt_factory = jax_stepper.make_pipeline_engine
     elif backend != "numpy":
         raise ValueError(f"unknown backend {backend!r}")
 

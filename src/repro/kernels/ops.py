@@ -1,8 +1,11 @@
 """Public jit'd entry points for the EC data plane.
 
-Dispatches to the Pallas kernels (compiled on TPU, interpret=True elsewhere —
-this container is CPU-only so interpret mode exercises the kernel bodies).
-Byte-level convenience wrappers handle bit-slicing at the boundary.
+Dispatches to the Pallas kernels: compiled on TPU, and run by the Pallas
+interpreter (`interpret=True`) on any other backend, which is how the
+tests exercise the kernel bodies under `JAX_PLATFORMS=cpu`. The batched
+entry points always run the compiled kernels on TPU and default to the
+numpy oracles in `repro.kernels.ref` elsewhere. Byte-level convenience
+wrappers handle bit-slicing at the boundary.
 """
 from __future__ import annotations
 
@@ -23,13 +26,24 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _use_kernel_default() -> bool:
-    """Batched entry points compile the kernels on TPU and fall back to
-    the numpy oracles in `repro.kernels.ref` everywhere else — unlike the
-    per-chunk wrappers above, whose interpret mode exists to *exercise*
-    the kernel bodies, the batched paths are sized for throughput and the
-    Pallas interpreter is not a performance proxy."""
-    return not _interpret_default()
+def _batched_path(use_kernel: bool | None,
+                  interpret: bool | None) -> tuple[bool, bool]:
+    """(use_kernel, interpret) for the batched entry points.
+
+    On TPU they always run the compiled kernels: a TPU run that took the
+    numpy oracle or the Pallas interpreter would verify and time other
+    code than the device path, so asking for either there raises.
+    Elsewhere the numpy oracles in `repro.kernels.ref` are the default —
+    the batched paths are sized for throughput and the Pallas interpreter
+    is not a performance proxy — and tests may ask for the kernels, which
+    then run interpreted."""
+    if not _interpret_default():
+        if use_kernel is False or interpret:
+            raise ValueError(
+                "on TPU the batched data plane runs the compiled kernels "
+                f"only (got use_kernel={use_kernel}, interpret={interpret})")
+        return True, False
+    return bool(use_kernel), True if interpret is None else interpret
 
 
 def gf256_matmul(
@@ -90,20 +104,18 @@ def gf256_scale_batch(
     by its own coefficient.
 
     The batched data-plane premultiply: one call covers every
-    (job, helper) chunk of a plan batch. `use_kernel=None` (the default)
-    compiles the Pallas kernel on TPU and takes the numpy oracle
-    (`ref.gf256_scale_batch_np`) elsewhere; the kernel path drives
-    `gf256_scale_planes` over an (M, W/block) grid — the same kernel body
-    as `gf256_matmul`, one grid row per chunk instead of one
-    `pallas_call` per chunk. Returns numpy on the ref path, a device
-    array on the kernel path.
+    (job, helper) chunk of a plan batch. On TPU it runs the compiled
+    Pallas kernel; elsewhere `use_kernel=None` (the default) takes the
+    numpy oracle (`ref.gf256_scale_batch_np`), see `_batched_path`. The
+    kernel path drives `gf256_scale_planes` over an (M, W/block) grid —
+    the same kernel body as `gf256_matmul`, one grid row per chunk
+    instead of one `pallas_call` per chunk. Returns numpy on the ref
+    path, a device array on the kernel path.
     """
     coeffs = np.asarray(coeffs, dtype=np.uint8).reshape(-1)
-    if use_kernel is None:
-        use_kernel = _use_kernel_default()
+    use_kernel, interpret = _batched_path(use_kernel, interpret)
     if coeffs.size == 0 or not use_kernel:
         return ref.gf256_scale_batch_np(coeffs, np.asarray(data))
-    interpret = _interpret_default() if interpret is None else interpret
     nbytes = data.shape[-1]
     masks = jnp.asarray(bitplane.coeff_to_masks_np(coeffs[:, None]))
     planes = bitplane.pack_jnp(jnp.asarray(data))
@@ -122,19 +134,18 @@ def xor_reduce_segments(
     padded) -> (G, nbytes): XOR-fold of each group's member rows.
 
     The batched data-plane merge: group g holds the payload rows arriving
-    at one (case, destination) in a round. `use_kernel=None` compiles on
-    TPU and takes `ref.xor_reduce_segments_np` elsewhere; the kernel path
-    gathers groups to a dense (G, Kmax, W) word tensor (index -1 reads an
-    all-zero row — XOR identity) and drives the `xor_reduce` kernel body
-    over a (G, W/block) grid. Returns numpy on the ref path, a device
+    at one (case, destination) in a round. On TPU it runs the compiled
+    kernel; elsewhere `use_kernel=None` takes `ref.xor_reduce_segments_np`
+    (see `_batched_path`). The kernel path gathers groups to a dense
+    (G, Kmax, W) word tensor (index -1 reads an all-zero row — XOR
+    identity) and drives the `xor_reduce` kernel body over a (G, W/block)
+    grid. Returns numpy on the ref path, a device
     array on the kernel path.
     """
     groups = np.asarray(groups, dtype=np.int64)
-    if use_kernel is None:
-        use_kernel = _use_kernel_default()
+    use_kernel, interpret = _batched_path(use_kernel, interpret)
     if groups.shape[0] == 0 or not use_kernel:
         return ref.xor_reduce_segments_np(np.asarray(chunks), groups)
-    interpret = _interpret_default() if interpret is None else interpret
     chunks = jnp.asarray(chunks)
     t, nbytes = chunks.shape
     pad = -nbytes % 4

@@ -47,7 +47,7 @@ def _group_kernel(x_ref, out_ref, *, k: int):
     acc = x_ref[0, 0, :]
     for i in range(1, k):
         acc = acc ^ x_ref[0, i, :]
-    out_ref[0] = acc
+    out_ref[0, 0, :] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
@@ -60,6 +60,10 @@ def xor_reduce_groups_words(
     payloads arriving at one (case, destination) in a round. Same reduce
     body as `xor_reduce_words`, driven over a (G, W/block_w) grid — one
     `pallas_call` folds every fan-in group of a whole round batch.
+
+    The output carries a unit middle axis, (G, 1, W), so the last two
+    dims of its (1, 1, block_w) block equal or tile the array's: TPU
+    lowering rejects a (1, block_w) block over a (G, W) array.
     """
     g, k, w = words.shape
     w_pad = -w % block_w
@@ -70,8 +74,8 @@ def xor_reduce_groups_words(
         functools.partial(_group_kernel, k=k),
         grid=(g, wp // block_w),
         in_specs=[pl.BlockSpec((1, k, block_w), lambda r, t: (r, 0, t))],
-        out_specs=pl.BlockSpec((1, block_w), lambda r, t: (r, t)),
-        out_shape=jax.ShapeDtypeStruct((g, wp), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 1, block_w), lambda r, t: (r, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((g, 1, wp), jnp.uint32),
         interpret=interpret,
     )(words)
-    return out[:, :w]
+    return out[:, 0, :w]

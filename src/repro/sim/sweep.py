@@ -7,10 +7,11 @@ under serial, thread and process dispatch — the executor only changes
 wall-clock, never output (apart from the wall-clock `planning_time`
 measurements themselves).
 
-Process dispatch uses the "spawn" start method by default: sweep workers
-import only the numpy-based `repro.core` stack (never JAX), so interpreter
-start-up is cheap and fork-safety issues with a JAX-initialized parent are
-avoided.
+Process dispatch uses the "spawn" start method by default, which avoids
+fork-safety issues with a JAX-initialized parent. Workers run the numpy
+object engine: importing `repro.core` imports JAX, but nothing they run
+initialises a JAX backend, so they never contend for an accelerator that
+the parent holds (`tests/test_sweep.py` pins this).
 """
 from __future__ import annotations
 
@@ -233,16 +234,9 @@ def _jax_pays_off(cases) -> bool:
         return False
     if not all(type(c.scenario.bw) is BandwidthTrace for c in cases):
         return False
-    try:
-        from repro.core.engine import jax_available
+    import jax
 
-        if not jax_available():
-            return False
-        import jax
-
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - broken jax install
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def _resolve_executor(executor: str, cases,
@@ -278,10 +272,11 @@ def run_sweep(
     "vectorized" (batched array engine — compatible cases step through
     `repro.core.engine` together), "jax" (the vectorized engine with
     jit-compiled device steppers from `repro.core.engine.jax_stepper`;
-    falls back to the numpy steppers per batch when jax is missing or a
-    batch is unsupported) or "auto" (the batched array engine: jax when
-    a device backend can amortize compilation — large trace-frozen
-    suites on an accelerator — else vectorized, the fastest CPU path).
+    raises without a usable jax, falls back to the numpy steppers per
+    batch when a batch is unsupported) or "auto" (the batched array
+    engine: jax when a device backend can amortize compilation — large
+    trace-frozen suites on an accelerator — else vectorized, the fastest
+    CPU path).
     Output is independent of the executor choice.
 
     `verify_bytes=k` additionally byte-verifies `k` sampled cases: their

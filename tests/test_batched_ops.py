@@ -47,6 +47,23 @@ def test_gf256_scale_batch_zero_and_one_coeffs(rng):
     assert np.array_equal(out[1], data[1])
 
 
+@pytest.mark.parametrize("kwargs", [
+    dict(use_kernel=False), dict(interpret=True),
+    dict(use_kernel=True, interpret=True)])
+@pytest.mark.parametrize("entry", ["gf256_scale_batch", "xor_reduce_segments"])
+def test_batched_entry_points_refuse_ref_and_interpret_on_tpu(
+        entry, kwargs, monkeypatch, rng):
+    """On TPU the batched entry points run the compiled kernels only: a
+    request for the numpy oracle or the interpreter there raises instead
+    of quietly leaving the device."""
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)  # "TPU"
+    data = rng.integers(0, 256, size=(2, 64), dtype=np.uint8)
+    args = {"gf256_scale_batch": (np.array([1, 2], dtype=np.uint8), data),
+            "xor_reduce_segments": (data, np.array([[0, 1]]))}[entry]
+    with pytest.raises(ValueError, match="compiled kernels only"):
+        getattr(ops, entry)(*args, **kwargs)
+
+
 # ---------------------------------------------------- xor_reduce_segments
 @pytest.mark.parametrize("nbytes", [4, 96, 1000])
 def test_xor_reduce_segments_paths(nbytes, rng):

@@ -1,8 +1,11 @@
 """JAX stepper parity: `run_sweep(executor="jax")` and the underlying
 `repro.core.engine.jax_stepper` programs must reproduce the reference
 engines — all 8 schemes, all three volatility regimes, 1e-6 relative
-tolerance with identical round counts and relay hops — and must fall
-back to the numpy vectorized engine cleanly when jax is unusable."""
+tolerance with identical round counts and relay hops — must raise when
+jax is unusable, and must fall back to the numpy steppers per batch only
+for batches the device engine cannot take."""
+import sys
+
 import numpy as np
 import pytest
 
@@ -14,9 +17,7 @@ from repro.ec.rs import RSCode
 from repro.sim.suite import MonteCarloSuite, SampleSpace, TraceSuite
 from repro.sim.sweep import run_sweep
 
-jax_stepper = pytest.importorskip(
-    "repro.core.engine.jax_stepper", reason="engine package unavailable")
-_HAS_JAX = jax_stepper.jax_available()
+from repro.core.engine import jax_stepper
 
 RTOL = 1e-6
 MULTI = ("mppr", "random", "msrepair")
@@ -40,7 +41,6 @@ def _assert_parity(ref, got, label=""):
 
 
 # ------------------------------------------------------------ parity matrix
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 @pytest.mark.parametrize("mode", ["jitter", "redraw", "markov"])
 @pytest.mark.parametrize("scheme", ALL_SCHEMES)
 def test_jax_matches_reference_all_schemes_all_regimes(scheme, mode):
@@ -56,7 +56,6 @@ def test_jax_matches_reference_all_schemes_all_regimes(scheme, mode):
         assert b.plan == a.plan, f"{scheme}/{mode} seed={s}"
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_jax_static_network_and_traces():
     static = [_scenario(seed=s, interval=None) for s in range(3)]
     for scheme in ("ppr", "bmf", "ppt"):
@@ -80,7 +79,6 @@ def test_jax_static_network_and_traces():
                 _assert_parity(a, b, f"trace cycle={cycle} {scheme}")
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_jax_epoch_horizon_grows_and_restores_replans(monkeypatch):
     """A live case outrunning the pre-sampled horizon must re-run with a
     doubled horizon — including rolling back BMF splices the aborted
@@ -104,7 +102,6 @@ def test_jax_epoch_horizon_grows_and_restores_replans(monkeypatch):
     assert grown, "the 2-epoch horizon must have overflowed"
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_jax_sweep_matches_serial():
     space = SampleSpace(
         codes=((4, 2), (6, 3)), cluster_sizes=(8,), chunk_mb=(8.0,),
@@ -124,7 +121,6 @@ def test_jax_sweep_matches_serial():
                 (cs.index, scheme)
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_jax_sweep_on_frozen_traces_matches_serial():
     space = SampleSpace(codes=((6, 3),), cluster_sizes=(8,), chunk_mb=(8.0,),
                         regimes=("hot2s",), failure_patterns=("single",))
@@ -140,19 +136,19 @@ def test_jax_sweep_on_frozen_traces_matches_serial():
 
 
 # ------------------------------------------------------------ fallback paths
-def test_jax_missing_falls_back_to_numpy_with_warning(monkeypatch):
-    """The no-jax path: executor='jax' must warn once and produce the
-    numpy vectorized engine's (identical) results."""
-    monkeypatch.setattr(jax_stepper, "_JAX_OK", False)
+def test_jax_missing_raises(monkeypatch):
+    """The no-jax path: backend='jax' must raise instead of quietly
+    running the numpy engine (a device run that silently leaves the
+    device is a wrong run), while the numpy backend still works."""
     scs = [_scenario(seed=s, cluster=8) for s in range(2)]
     ref = run_scheme_vectorized(scs, "ppr")
-    with pytest.warns(RuntimeWarning, match="jax is not importable"):
-        got = run_scheme_vectorized(scs, "ppr", backend="jax")
-    for a, b in zip(ref, got):
-        assert b.total_time == a.total_time
+    monkeypatch.setitem(sys.modules, "jax", None)
+    with pytest.raises(ImportError):
+        run_scheme_vectorized(scs, "ppr", backend="jax")
+    assert [r.total_time for r in run_scheme_vectorized(scs, "ppr")] == \
+        [r.total_time for r in ref]
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_jax_non_persistent_shares_fall_back():
     """Epoch-keyed Dirichlet redraws cannot be pretabulated on device:
     the factory must decline and the batch must still match serial."""
@@ -172,7 +168,6 @@ def test_jax_non_persistent_shares_fall_back():
         _assert_parity(a, b, "non-persistent fallback")
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_jax_unsupported_helper_ids_fall_back_per_case():
     """Helper ids >= 64 cannot be bitmask-compiled; those cases must drop
     to the object engine while the rest of the batch runs on device."""
@@ -188,7 +183,6 @@ def test_jax_unsupported_helper_ids_fall_back_per_case():
         _assert_parity(a, b, "fallback")
 
 
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_bucketing_shares_compiled_programs():
     """Batches whose raw shapes differ only within a pow2 bucket must pad
     to the same padded shapes (one compiled program per (N, bucket))."""
@@ -208,7 +202,6 @@ def test_bucketing_shares_compiled_programs():
 
 
 # ------------------------------------------------------ program-cache reuse
-@pytest.mark.skipif(not _HAS_JAX, reason="jax not installed")
 def test_compiled_programs_reused_across_run_sweep_calls():
     """The jit steppers live in a module-global cache keyed by padded
     pow2 shapes: a second `run_sweep(executor="jax")` over a fresh but

@@ -107,6 +107,36 @@ def test_process_pool_path_matches_serial(monkeypatch):
             assert cr.results[s].round_times == cg.results[s].round_times
 
 
+def _probe_case(args):
+    """Pool task for the backend probe below: run the case as the pool
+    would, then record whether this worker initialised a JAX backend."""
+    import dataclasses
+
+    from jax._src import xla_bridge
+
+    import repro.sim.sweep as sweep_mod
+
+    res = sweep_mod._run_case(*args)
+    return dataclasses.replace(res, params={
+        **res.params, "backend_initialised": xla_bridge.backends_are_initialized()})
+
+
+def test_process_pool_workers_initialise_no_backend(monkeypatch):
+    """Spawned sweep workers import JAX (through `repro.core`) but must
+    never initialise a backend: on an accelerator host the parent holds
+    the device, and a worker reaching for it would fail or hang."""
+    import jax
+
+    import repro.sim.sweep as sweep_mod
+
+    jax.devices()                       # the parent holds its backend
+    monkeypatch.setattr(sweep_mod, "_MIN_CASES_PER_WORKER", 1)
+    monkeypatch.setattr(sweep_mod, "_run_case_star", _probe_case)
+    got = run_sweep(_small_mc_suite(num=4), executor="process", max_workers=2)
+    flags = [c.params["backend_initialised"] for c in got.cases]
+    assert flags == [False] * 4
+
+
 def test_process_executor_spawn_amortization_fallback():
     """Below the spawn-amortization threshold the process executor must
     warn and fall back to serial (identical results) instead of paying
