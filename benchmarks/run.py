@@ -20,7 +20,7 @@ def main() -> None:
     use_compile_cache(Path(__file__).resolve().parent.parent)
     from benchmarks import (bench_ablation, bench_aliyun, bench_dataplane,
                             bench_fig8, bench_fig9, bench_fig10, bench_fig11,
-                            bench_kernels, bench_sweep, bench_table2)
+                            bench_sweep, bench_table2)
     modules = [
         ("table2", bench_table2),
         ("fig8", bench_fig8),
@@ -28,7 +28,6 @@ def main() -> None:
         ("fig10", bench_fig10),
         ("fig11", bench_fig11),
         ("aliyun", bench_aliyun),
-        ("kernels", bench_kernels),
         ("ablation", bench_ablation),
         ("sweep", bench_sweep),
         ("dataplane", bench_dataplane),

@@ -44,8 +44,10 @@ from __future__ import annotations
 import dataclasses
 from collections.abc import Sequence
 
+import jax
 import numpy as np
 
+from repro import spans
 from repro.core.engine.arrays import PlanArrays, compile_plan
 from repro.core.plan import RepairPlan
 from repro.ec.rs import RSCode
@@ -145,116 +147,144 @@ def execute_plans_batch(
     bit-for-bit) and relay-aware `bytes_moved` — byte-identical to
     running `executor.execute_plan` case by case.
     """
-    pas = _as_plan_arrays(plans)
-    B = len(pas)
-    if B == 0:
-        return BatchExecutionResult([], np.zeros(0, bool),
-                                    np.zeros(0, np.int64))
-    codes = list(codes) if isinstance(codes, Sequence) else [codes] * B
-    cws = [np.asarray(cw, dtype=np.uint8) for cw in codewords]
-    if len(codes) != B or len(cws) != B:
-        raise ValueError("plans, codes and codewords must align")
-    nbytes = cws[0].shape[-1]
-    if any(cw.shape[-1] != nbytes for cw in cws):
-        raise ValueError("all codewords must share one chunk size")
-    N = max(pa.num_nodes for pa in pas)
-    block_maps = []
-    for b, pa in enumerate(pas):
-        bmap = None if block_of is None else block_of[b]
-        if bmap is None:
-            bmap = identity_block_map(max(N, codes[b].n), codes[b].n)
-        else:
-            bmap = np.asarray(bmap, dtype=np.int64)
-            if bmap.size < N:
-                bmap = np.concatenate(
-                    [bmap, np.full(N - bmap.size, -1, dtype=np.int64)])
-        block_maps.append(bmap)
-    jmax = max(pa.num_jobs for pa in pas)
-    S = jmax * N
-    buf = np.zeros((B, S, nbytes), dtype=np.uint8)
-    occupied = np.zeros((B, S), dtype=bool)
+    with spans.span("repro.dataplane.batch"):
+        return _execute_plans_batch(plans, codes, codewords, block_of,
+                                    use_kernel, interpret)
 
-    # ---- init: batched coefficients + one batched premultiply
-    coeffs = _repair_coeffs(pas, codes, block_maps)
-    tb, tslot, tcoef, tdata = [], [], [], []
-    for b, pa in enumerate(pas):
-        for j in range(pa.num_jobs):
-            hl = int(pa.job_helpers_len[j])
-            hs = pa.job_helpers[j, :hl].astype(np.int64)
-            tb.extend([b] * hl)
-            tslot.extend(j * N + hs)
-            tcoef.extend(coeffs[b][j])
-            tdata.append(cws[b][block_maps[b][hs]])
+
+def _pull(x) -> np.ndarray:
+    """A GF(256) step's result on the host, copied inside the d2h span;
+    its `bytes` counts device arrays only (0 for the numpy path's)."""
+    with spans.span("repro.dataplane.d2h"):
+        spans.count("bytes", x.nbytes if isinstance(x, jax.Array) else 0)
+        return np.asarray(x, dtype=np.uint8)
+
+
+def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
+                         interpret) -> BatchExecutionResult:
+    """`execute_plans_batch`'s body, one span per step. A helper, so that
+    the caller's batch span also holds the frees of its buffers."""
+    with spans.span("repro.dataplane.prepare"):
+        pas = _as_plan_arrays(plans)
+        B = len(pas)
+        if B == 0:
+            return BatchExecutionResult([], np.zeros(0, bool),
+                                        np.zeros(0, np.int64))
+        codes = list(codes) if isinstance(codes, Sequence) else [codes] * B
+        cws = [np.asarray(cw, dtype=np.uint8) for cw in codewords]
+        if len(codes) != B or len(cws) != B:
+            raise ValueError("plans, codes and codewords must align")
+        nbytes = cws[0].shape[-1]
+        if any(cw.shape[-1] != nbytes for cw in cws):
+            raise ValueError("all codewords must share one chunk size")
+        N = max(pa.num_nodes for pa in pas)
+        block_maps = []
+        for b, pa in enumerate(pas):
+            bmap = None if block_of is None else block_of[b]
+            if bmap is None:
+                bmap = identity_block_map(max(N, codes[b].n), codes[b].n)
+            else:
+                bmap = np.asarray(bmap, dtype=np.int64)
+                if bmap.size < N:
+                    bmap = np.concatenate(
+                        [bmap, np.full(N - bmap.size, -1, dtype=np.int64)])
+            block_maps.append(bmap)
+        jmax = max(pa.num_jobs for pa in pas)
+        S = jmax * N
+        buf = np.zeros((B, S, nbytes), dtype=np.uint8)
+        occupied = np.zeros((B, S), dtype=bool)
+        coeffs = _repair_coeffs(pas, codes, block_maps)
+
+        # flat round-major transfer table across the batch
+        fb = np.concatenate([np.full(pa.num_transfers, b, dtype=np.int64)
+                             for b, pa in enumerate(pas)])
+        fround = np.concatenate([
+            np.repeat(np.arange(pa.num_rounds, dtype=np.int64),
+                      np.diff(pa.round_start)) for pa in pas])
+        fsrc = np.concatenate([pa.t_job_idx.astype(np.int64) * N + pa.t_src
+                               for pa in pas])
+        fdst = np.concatenate([pa.t_job_idx.astype(np.int64) * N + pa.t_dst
+                               for pa in pas])
+        fhops = np.concatenate([pa.t_path_len.astype(np.int64) - 1
+                                for pa in pas])
+        bytes_moved = np.zeros(B, dtype=np.int64)
+        np.add.at(bytes_moved, fb, nbytes * fhops)
+
+    # ---- init: one batched premultiply of every helper chunk
+    with spans.span("repro.dataplane.stage"):
+        tb, tslot, tcoef, tdata = [], [], [], []
+        for b, pa in enumerate(pas):
+            for j in range(pa.num_jobs):
+                hl = int(pa.job_helpers_len[j])
+                hs = pa.job_helpers[j, :hl].astype(np.int64)
+                tb.extend([b] * hl)
+                tslot.extend(j * N + hs)
+                tcoef.extend(coeffs[b][j])
+                tdata.append(cws[b][block_maps[b][hs]])
+        staged = np.concatenate(tdata) if tb else None
     if tb:
-        pre = np.asarray(ops.gf256_scale_batch(
-            np.asarray(tcoef, dtype=np.uint8), np.concatenate(tdata),
-            use_kernel=use_kernel, interpret=interpret), dtype=np.uint8)
-        buf[np.asarray(tb), np.asarray(tslot)] = pre
-        occupied[np.asarray(tb), np.asarray(tslot)] = True
-
-    # ---- flat round-major transfer table across the batch
-    fb = np.concatenate([np.full(pa.num_transfers, b, dtype=np.int64)
-                         for b, pa in enumerate(pas)])
-    fround = np.concatenate([
-        np.repeat(np.arange(pa.num_rounds, dtype=np.int64),
-                  np.diff(pa.round_start)) for pa in pas])
-    fsrc = np.concatenate([pa.t_job_idx.astype(np.int64) * N + pa.t_src
-                           for pa in pas])
-    fdst = np.concatenate([pa.t_job_idx.astype(np.int64) * N + pa.t_dst
-                           for pa in pas])
-    fhops = np.concatenate([pa.t_path_len.astype(np.int64) - 1
-                            for pa in pas])
-
-    bytes_moved = np.zeros(B, dtype=np.int64)
-    np.add.at(bytes_moved, fb, nbytes * fhops)
+        with spans.span("repro.dataplane.premultiply"):
+            pre = ops.gf256_scale_batch(
+                np.asarray(tcoef, dtype=np.uint8), staged,
+                use_kernel=use_kernel, interpret=interpret)
+        del staged              # the staging copy must not outlive the call
+        pre = _pull(pre)
+        with spans.span("repro.dataplane.scatter"):
+            buf[np.asarray(tb), np.asarray(tslot)] = pre
+            occupied[np.asarray(tb), np.asarray(tslot)] = True
 
     R = max((pa.num_rounds for pa in pas), default=0)
     for r in range(R):
-        rows = np.nonzero(fround == r)[0]
-        if not rows.size:
-            continue
-        rb, rsrc, rdst = fb[rows], fsrc[rows], fdst[rows]
-        if not occupied[rb, rsrc].all():
-            bad = int(np.nonzero(~occupied[rb, rsrc])[0][0])
-            raise ValueError(
-                f"round {r}: case {int(rb[bad])} transfer sources slot "
-                f"(job {int(rsrc[bad]) // N}, node {int(rsrc[bad]) % N}) "
-                "which holds no buffer — consumed in an earlier round? "
-                "execute_plans_batch requires a validate_plan-clean plan")
-        payload = buf[rb, rsrc]                      # gather (T_r, nbytes)
-        buf[rb, rsrc] = 0                            # two-phase consume
-        occupied[rb, rsrc] = False
-        # fan-in groups per (case, destination slot), transfer order kept
-        key = rb * S + rdst
-        order = np.argsort(key, kind="stable")
-        skey = key[order]
-        boundary = np.empty(order.size, dtype=bool)
-        boundary[0] = True
-        np.not_equal(skey[1:], skey[:-1], out=boundary[1:])
-        starts = np.nonzero(boundary)[0]
-        counts = np.diff(np.append(starts, order.size))
-        groups = np.full((starts.size, int(counts.max())), -1, dtype=np.int64)
-        pos = np.arange(order.size) - np.repeat(starts, counts)
-        groups[np.repeat(np.arange(starts.size), counts), pos] = order
-        folded = np.asarray(ops.xor_reduce_segments(
-            payload, groups, use_kernel=use_kernel, interpret=interpret),
-            dtype=np.uint8)
-        gkey = skey[starts]
-        gb, gs = gkey // S, gkey % S
-        buf[gb, gs] ^= folded                        # zeros when unoccupied
-        occupied[gb, gs] = True
+        with spans.span("repro.dataplane.gather"):
+            rows = np.nonzero(fround == r)[0]
+            if not rows.size:
+                continue
+            rb, rsrc, rdst = fb[rows], fsrc[rows], fdst[rows]
+            if not occupied[rb, rsrc].all():
+                bad = int(np.nonzero(~occupied[rb, rsrc])[0][0])
+                raise ValueError(
+                    f"round {r}: case {int(rb[bad])} transfer sources slot "
+                    f"(job {int(rsrc[bad]) // N}, node {int(rsrc[bad]) % N}) "
+                    "which holds no buffer — consumed in an earlier round? "
+                    "execute_plans_batch requires a validate_plan-clean plan")
+            payload = buf[rb, rsrc]                  # gather (T_r, nbytes)
+            buf[rb, rsrc] = 0                        # two-phase consume
+            occupied[rb, rsrc] = False
+            # fan-in groups per (case, destination slot), transfer order kept
+            key = rb * S + rdst
+            order = np.argsort(key, kind="stable")
+            skey = key[order]
+            boundary = np.empty(order.size, dtype=bool)
+            boundary[0] = True
+            np.not_equal(skey[1:], skey[:-1], out=boundary[1:])
+            starts = np.nonzero(boundary)[0]
+            counts = np.diff(np.append(starts, order.size))
+            groups = np.full((starts.size, int(counts.max())), -1,
+                             dtype=np.int64)
+            pos = np.arange(order.size) - np.repeat(starts, counts)
+            groups[np.repeat(np.arange(starts.size), counts), pos] = order
+        with spans.span("repro.dataplane.fold"):
+            folded = ops.xor_reduce_segments(
+                payload, groups, use_kernel=use_kernel, interpret=interpret)
+        folded = _pull(folded)
+        with spans.span("repro.dataplane.accumulate"):
+            gkey = skey[starts]
+            gb, gs = gkey // S, gkey % S
+            buf[gb, gs] ^= folded                    # zeros when unoccupied
+            occupied[gb, gs] = True
 
     # ---- verify every job's requestor buffer against the lost block
-    recon: list[dict[int, np.ndarray]] = [dict() for _ in range(B)]
-    verified = np.ones(B, dtype=bool)
-    for b, pa in enumerate(pas):
-        for j in range(pa.num_jobs):
-            slot = j * N + int(pa.job_requestor[j])
-            got = buf[b, slot].copy()
-            recon[b][int(pa.job_id[j])] = got
-            fblock = int(block_maps[b][pa.job_failed[j]])
-            if not (occupied[b, slot]
-                    and np.array_equal(got, cws[b][fblock])):
-                verified[b] = False
+    with spans.span("repro.dataplane.verify"):
+        recon: list[dict[int, np.ndarray]] = [dict() for _ in range(B)]
+        verified = np.ones(B, dtype=bool)
+        for b, pa in enumerate(pas):
+            for j in range(pa.num_jobs):
+                slot = j * N + int(pa.job_requestor[j])
+                got = buf[b, slot].copy()
+                recon[b][int(pa.job_id[j])] = got
+                fblock = int(block_maps[b][pa.job_failed[j]])
+                if not (occupied[b, slot]
+                        and np.array_equal(got, cws[b][fblock])):
+                    verified[b] = False
     return BatchExecutionResult(reconstructed=recon, verified=verified,
                                 bytes_moved=bytes_moved)

@@ -1,0 +1,276 @@
+"""Program spans of the byte data plane (`repro.spans`): what a profiler
+trace shows of `execute_plans_batch`, what `spans.totals()` records, that
+nothing is recorded with the profiler off, and the benchmark's readers of
+those totals (`bench/metrics/`)."""
+import glob
+import importlib.util
+import os
+import sys
+import types
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+from jax.profiler import ProfileData, TraceAnnotation
+
+from repro import spans
+from repro.core import executor, topology
+from repro.core.bandwidth import BandwidthProcess, IngressModel
+from repro.core.engine.arrays import (compile_plan, decompile,
+                                      relabel_plan_nodes)
+from repro.core.engine.dataplane import execute_plans_batch
+from repro.core.simulator import Scenario
+from repro.ec.rs import RSCode
+from repro.ec.stripe import place_stripes
+from repro.sim.sweep import _verify_plan
+
+METRICS = Path(__file__).resolve().parents[1] / "bench" / "metrics"
+NBYTES = 256
+BATCH = "repro.dataplane.batch"
+STEPS = ("prepare", "stage", "premultiply", "d2h", "scatter", "gather",
+         "fold", "accumulate", "verify")
+NAMES = (BATCH,) + tuple(f"repro.dataplane.{s}" for s in STEPS)
+READERS = ("dataplane_host_ms_per_lost_MiB.repair",
+           "dataplane_copy_back_ms_per_lost_MiB.repair",
+           "dataplane_gf_call_ms_per_lost_MiB.repair",
+           "device_to_host_bytes_per_lost_byte.repair")
+
+
+def _batch(kind: str) -> dict:
+    """Three stripes: placed RS(9,6) with one lost block under BMF, or
+    RS(14,10) with two lost blocks under MSRepair (simulator placement)."""
+    n, k, failed, scheme, cluster, placed = {
+        "rs96_bmf_placed": (9, 6, (2,), "bmf", 12, True),
+        "rs1410_msrepair": (14, 10, (1, 5), "msrepair", 16, False),
+    }[kind]
+    code = RSCode(n, k)
+    rng = np.random.default_rng(n)
+    m = topology.heterogeneous_matrix(cluster, low=3, high=30, seed=n)
+    sc = Scenario(num_nodes=cluster, code=code, failed=failed,
+                  bw=BandwidthProcess(base=m, change_interval=2.0, seed=n,
+                                      mode="markov"),
+                  ingress=IngressModel(seed=n), chunk_mb=4.0)
+    plan = compile_plan(_verify_plan(sc, scheme, n, bmf_optimize_all=False))
+    cws = [code.encode(rng.integers(0, 256, size=(k, NBYTES), dtype=np.uint8))
+           for _ in range(3)]
+    if placed:
+        stripes = place_stripes(3, code, cluster)
+        plans = [relabel_plan_nodes(plan, s.perm(cluster)) for s in stripes]
+        bmaps = [s.block_map(cluster) for s in stripes]
+    else:
+        plans, bmaps = [plan] * 3, None
+    return dict(plans=plans, code=code, cws=cws, block_of=bmaps)
+
+
+def _run(b: dict):
+    return execute_plans_batch(b["plans"], b["code"], b["cws"],
+                               block_of=b["block_of"], use_kernel=True)
+
+
+def _traced(b: dict, tmp: str) -> types.SimpleNamespace:
+    """The batch, its result, `spans.totals()` before and after, and the
+    trace's `caller` and program span events."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    before = spans.totals()
+    with jax.profiler.trace(tmp, profiler_options=opts):
+        with TraceAnnotation("caller"):
+            res = _run(b)
+    after = spans.totals()
+    (path,) = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
+                        recursive=True)
+    events = []                      # (line, name, start_ns, end_ns)
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            events += [((plane.name, i), e.name, e.start_ns,
+                        e.start_ns + e.duration_ns) for e in line.events
+                       if e.name == "caller" or e.name.startswith("repro.")]
+    return types.SimpleNamespace(b=b, res=res, before=before, after=after,
+                                 events=events)
+
+
+def _delta(before: dict, after: dict, name: str, key: str):
+    return after.get(name, {}).get(key, 0) - before.get(name, {}).get(key, 0)
+
+
+def _inside(ev, outer) -> bool:
+    return (ev[0] == outer[0] and outer[2] <= ev[2] and ev[3] <= outer[3]
+            and ev is not outer)
+
+
+def _d2h_bytes(b: dict) -> int:
+    """(premultiply rows + fold groups of every round) x cell bytes."""
+    rows = groups = 0
+    for pa in b["plans"]:
+        rows += int(pa.job_helpers_len.sum())
+        for r in range(pa.num_rounds):
+            sl = pa.round_rows(r)
+            groups += len(set(zip(pa.t_job_idx[sl].tolist(),
+                                  pa.t_dst[sl].tolist())))
+    return (rows + groups) * NBYTES
+
+
+def _lost_bytes(b: dict) -> int:
+    return NBYTES * sum(pa.num_jobs for pa in b["plans"])
+
+
+def _reader(name: str):
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + name.replace(".", "_"), METRICS / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+KINDS = ("rs96_bmf_placed", "rs1410_msrepair")
+
+
+@pytest.fixture(scope="module", params=KINDS)
+def traced(request, tmp_path_factory):
+    return _traced(_batch(request.param),
+                   str(tmp_path_factory.mktemp(request.param)))
+
+
+def test_every_span_is_in_the_trace(traced):
+    assert {e[1] for e in traced.events} == set(NAMES) | {"caller"}
+
+
+def test_steps_nest_in_batch_nest_in_caller(traced):
+    events = traced.events
+    callers = [e for e in events if e[1] == "caller"]
+    batches = [e for e in events if e[1] == BATCH]
+    assert len(callers) == 1 and len(batches) == 1
+    assert _inside(batches[0], callers[0])
+    for e in events:
+        if e[1] not in ("caller", BATCH):
+            assert _inside(e, batches[0]), e[1]
+            # steps are siblings: none holds another (d2h is no child of
+            # the GF call before it)
+            assert not any(_inside(o, e) for o in events), e[1]
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_trace_counts_match_totals(traced, name):
+    in_file = sum(e[1] == name for e in traced.events)
+    assert in_file == _delta(traced.before, traced.after, name, "count") > 0
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_trace_self_time_matches_totals(traced, name):
+    events = traced.events
+    self_ns = 0
+    for e in (e for e in events if e[1] == name):
+        children = [o for o in events
+                    if o[1].startswith("repro.") and _inside(o, e)]
+        self_ns += (e[3] - e[2]) - sum(o[3] - o[2] for o in children)
+    recorded = _delta(traced.before, traced.after, name, "self_s")
+    assert abs(self_ns * 1e-9 - recorded) <= max(1e-3, 0.05 * recorded)
+
+
+def test_d2h_bytes_count_every_result(traced):
+    assert _delta(traced.before, traced.after, "repro.dataplane.d2h",
+                  "bytes") == _d2h_bytes(traced.b)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_d2h_bytes_zero_on_numpy_path(kind, tmp_path):
+    """The numpy ref path hands back host arrays: nothing is copied."""
+    b = _batch(kind)
+    before = spans.totals()
+    with jax.profiler.trace(str(tmp_path)):
+        execute_plans_batch(b["plans"], b["code"], b["cws"],
+                            block_of=b["block_of"], use_kernel=False)
+    after = spans.totals()
+    assert _delta(before, after, "repro.dataplane.d2h", "count") > 0
+    assert _delta(before, after, "repro.dataplane.d2h", "bytes") == 0
+
+
+def test_profiler_off_records_nothing_and_changes_nothing(traced):
+    b, traced_res = traced.b, traced.res
+    before = spans.totals()
+    res = _run(b)
+    assert spans.totals() == before
+    for got in (res, traced_res):
+        assert got.all_verified
+        for c, (pa, cw) in enumerate(zip(b["plans"], b["cws"])):
+            ser = executor.execute_plan(
+                decompile(pa), b["code"], cw, use_kernel=False,
+                block_of=None if b["block_of"] is None else b["block_of"][c])
+            assert int(got.bytes_moved[c]) == ser.bytes_moved
+            assert got.reconstructed[c].keys() == ser.reconstructed.keys()
+            for jid, blk in ser.reconstructed.items():
+                assert np.array_equal(got.reconstructed[c][jid],
+                                      np.asarray(blk))
+    assert np.array_equal(res.bytes_moved, traced_res.bytes_moved)
+
+
+def test_profiler_off_costs_one_check_per_span(traced, monkeypatch):
+    """Off, every span is one `is_enabled()` call and the shared null
+    context: no annotation is made and no count is kept."""
+    checks = []
+
+    class Off:
+        def __init__(self, name):
+            raise AssertionError("no annotation may be made")
+
+        @staticmethod
+        def is_enabled():
+            checks.append(1)
+            return False
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Off)
+    assert spans.span("a") is spans.span("b")
+    checks.clear()
+    _run(traced.b)
+    assert len(checks) == sum(_delta(traced.before, traced.after, n, "count")
+                              for n in NAMES)
+    kept = spans.totals()
+    spans.count("bytes", 1)                 # no open span: nothing kept
+    assert spans.totals() == kept
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("reader", READERS)
+def test_reader_reads_the_window(kind, reader, monkeypatch, tmp_path):
+    """Each reader, on a context built after a traced call, returns its
+    value by hand from fresh totals."""
+    monkeypatch.setattr(spans, "_totals", {})
+    b = _batch(kind)
+    _traced(b, str(tmp_path))
+    t = spans.totals()
+    lost = _lost_bytes(b)
+    mib = lost / 2**20
+
+    def self_ms(*steps):
+        return 1e3 * sum(t[f"repro.dataplane.{s}"]["self_s"]
+                         for s in steps) / mib
+
+    want = {
+        READERS[0]: self_ms("prepare", "stage", "scatter", "gather",
+                            "accumulate", "verify"),
+        READERS[1]: self_ms("d2h"),
+        READERS[2]: 1e3 * sum(t[f"repro.dataplane.{s}"]["total_s"]
+                              for s in ("premultiply", "fold")) / mib,
+        READERS[3]: _d2h_bytes(b) / lost,
+    }[reader]
+    ctx = types.SimpleNamespace(calls=[], trace=None, e2e={},
+                                lost_bytes=lost, peak=None)
+    assert _reader(reader).read(ctx) == pytest.approx(want, rel=1e-12)
+    assert want > 0
+
+
+@pytest.mark.parametrize("case", ("no_module", "no_batch", "no_lost_bytes"))
+@pytest.mark.parametrize("reader", READERS)
+def test_reader_returns_none_without_spans(reader, case, monkeypatch):
+    monkeypatch.setattr(spans, "_totals", {
+        BATCH: {"count": 0 if case == "no_batch" else 1, "total_ns": 10,
+                "self_ns": 1},
+        **{f"repro.dataplane.{s}": {"count": 1, "total_ns": 1, "self_ns": 1,
+                                    "bytes": 8} for s in STEPS}})
+    if case == "no_module":
+        monkeypatch.setitem(sys.modules, "repro.spans", None)
+    ctx = types.SimpleNamespace(
+        calls=[], trace=None, e2e={}, peak=None,
+        lost_bytes=0 if case == "no_lost_bytes" else 1024)
+    assert _reader(reader).read(ctx) is None
