@@ -21,8 +21,9 @@ The compile/plan/execute split mirrors a small compiler stack:
   shapes) behind `run_sweep(executor="jax")`; planning and replanning
   stay on the host, execution runs on the accelerator;
 * `repro.core.engine.dataplane` — the byte data plane: batches of
-  compiled plans executed over *real bytes* (`(B, slots, nbytes)`
-  buffer tensors, batched GF(256) premultiply + segment-XOR through
+  compiled plans executed over *real bytes* (a compact `(rows, nbytes)`
+  store of the (case, slot) buffers, kept on the device on the kernel
+  path; batched GF(256) premultiply + segment-XOR through
   `repro.kernels.ops`), byte-identical to the serial oracle in
   `repro.core.executor`.
 

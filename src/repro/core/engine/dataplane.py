@@ -4,24 +4,30 @@ This is the array-native twin of `repro.core.executor.execute_plan` — the
 module that *runs* a repair plan instead of timing it. Where the serial
 oracle walks one plan's transfers with a dict of per-node device buffers
 and one kernel call per chunk, this engine lowers a whole batch of
-compiled plans into dense buffer tensors and executes every round as
-three array steps:
+compiled plans into one compact `(U, nbytes)` store — a row per (case,
+slot) key the batch touches; slot `j * N + v` is node v's buffer for job
+j — and executes every round as three array steps:
 
-1. **gather** — all of the round's payload rows, batch-wide, out of a
-   `(B, S, nbytes)` buffer tensor (S = jobs x nodes slots; slot
-   `j * N + v` is node v's buffer for job j);
-2. **GF(256) premultiply** (init round only) — every helper chunk scaled
+1. **GF(256) premultiply** (init round only) — every helper chunk scaled
    by its repair coefficient in one `kernels.ops.gf256_scale_batch` call,
    with the coefficients themselves computed batched by
-   `RSCode.repair_coeffs_batch` (one lockstep Gauss-Jordan per code);
+   `RSCode.repair_coeffs_batch` (one lockstep Gauss-Jordan per code); the
+   result becomes the store's first rows;
+2. **gather** — all of the round's payload rows, batch-wide, taken out
+   of the store into a `(T_r, nbytes)` array;
 3. **segment-XOR** — arrivals folded per (case, destination) group by one
-   `kernels.ops.xor_reduce_segments` call and XOR-scattered back.
+   `kernels.ops.xor_reduce_segments` call; one update consumes the
+   round's sources and XORs the folded rows into their destinations.
 
 On TPU the two ops drive the Pallas kernel bodies over a grid (one
 `pallas_call` per step instead of one per chunk); everywhere else they
 fall back to the numpy oracles in `repro.kernels.ref`, so the batched
 path stays a genuine throughput win on CPU too (`benchmarks/
-bench_dataplane.py` gates it).
+bench_dataplane.py` gates it). The store follows the premultiply's
+answer: a device array on the kernel path, where the round state stays
+on the device and only the rebuilt blocks come back in one copy, and a
+host array, updated in place, on the ref path. The host keeps only the
+index plan and the rows' occupancy.
 
 Execution semantics match the serial oracle exactly: within a round all
 sources are consumed before any arrival lands (store-and-forward
@@ -42,9 +48,11 @@ sweep's byte-verification layer passes the mapping of a *placed* stripe
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections.abc import Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import spans
@@ -153,11 +161,85 @@ def execute_plans_batch(
 
 
 def _pull(x) -> np.ndarray:
-    """A GF(256) step's result on the host, copied inside the d2h span;
-    its `bytes` counts device arrays only (0 for the numpy path's)."""
+    """The rebuilt blocks on the host, copied inside the d2h span; its
+    `bytes` counts device arrays only (0 for the numpy path's)."""
     with spans.span("repro.dataplane.d2h"):
         spans.count("bytes", x.nbytes if isinstance(x, jax.Array) else 0)
         return np.asarray(x, dtype=np.uint8)
+
+
+# The store on the device is (U, width / 128, 128) uint8, each row a
+# contiguous run of the TPU's tiles: in a 2-D (U, nbytes) array a row is
+# spread 128 bytes to a tile, and row copies ran 6-12x slower on a TPU
+# v5e. The updates go row by row, since XLA's TPU gather and scatter of
+# whole 1 MiB rows unroll into megabytes of code per shape. One program
+# per shape each; `_fold_in_device` donates the store, so no round
+# copies it.
+_LANES = 128
+
+
+def _tiled(x):
+    """(n, nbytes) -> (n, width / 128, 128), zero-padded to the width."""
+    x = jnp.pad(x, ((0, 0), (0, -x.shape[1] % _LANES)))
+    return x.reshape(x.shape[0], -1, _LANES)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _grow_device(pre, rows):
+    return jnp.pad(_tiled(pre), ((0, rows - pre.shape[0]), (0, 0), (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _take_device(store, rows, nbytes):
+    out = jax.lax.map(
+        lambda i: jax.lax.dynamic_index_in_dim(store, i, keepdims=False),
+        rows)
+    return out.reshape(rows.shape[0], -1)[:, :nbytes]
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _fold_in_device(store, src, dst, folded):
+    zero = jnp.zeros((1,) + store.shape[1:], store.dtype)
+    folded = _tiled(folded)
+
+    def consume(k, st):
+        return jax.lax.dynamic_update_slice_in_dim(st, zero, src[k], 0)
+
+    def add(k, st):
+        row = (jax.lax.dynamic_slice_in_dim(st, dst[k], 1)
+               ^ jax.lax.dynamic_slice_in_dim(folded, k, 1))
+        return jax.lax.dynamic_update_slice_in_dim(st, row, dst[k], 0)
+
+    store = jax.lax.fori_loop(0, src.shape[0], consume, store)
+    return jax.lax.fori_loop(0, dst.shape[0], add, store)
+
+
+def _grow(pre, rows: int):
+    """The store: the premultiplied rows first, zero rows after, where
+    the premultiply answered (the device, or host memory)."""
+    if isinstance(pre, jax.Array):
+        return _grow_device(pre, rows)
+    store = np.zeros((rows, pre.shape[1]), dtype=np.uint8)
+    store[:len(pre)] = pre
+    return store
+
+
+def _take(store, rows: np.ndarray, nbytes: int):
+    """The store's `rows`, as a new (len(rows), nbytes) array beside it."""
+    if isinstance(store, jax.Array):
+        return _take_device(store, rows, nbytes)
+    return store[rows]
+
+
+def _fold_in(store, src: np.ndarray, dst: np.ndarray, folded):
+    """Consume the round's source rows (zeroed: two-phase), then XOR each
+    folded row into its destination row (an empty row is zeros, the XOR
+    identity). Returns the updated store."""
+    if isinstance(store, jax.Array):
+        return _fold_in_device(store, src, dst, folded)
+    store[src] = 0
+    store[dst] ^= np.asarray(folded)
+    return store
 
 
 def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
@@ -189,10 +271,7 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
                     bmap = np.concatenate(
                         [bmap, np.full(N - bmap.size, -1, dtype=np.int64)])
             block_maps.append(bmap)
-        jmax = max(pa.num_jobs for pa in pas)
-        S = jmax * N
-        buf = np.zeros((B, S, nbytes), dtype=np.uint8)
-        occupied = np.zeros((B, S), dtype=bool)
+        S = max(pa.num_jobs for pa in pas) * N
         coeffs = _repair_coeffs(pas, codes, block_maps)
 
         # flat round-major transfer table across the batch
@@ -210,53 +289,74 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
         bytes_moved = np.zeros(B, dtype=np.int64)
         np.add.at(bytes_moved, fb, nbytes * fhops)
 
-    # ---- init: one batched premultiply of every helper chunk
-    with spans.span("repro.dataplane.stage"):
-        tb, tslot, tcoef, tdata = [], [], [], []
+        # the compact store: one row per (case, slot) the batch touches,
+        # keyed b * S + slot (slot j * N + v is node v's buffer for job
+        # j); the premultiplied helper rows first, in staging order
+        hkey, rkey = [], []
         for b, pa in enumerate(pas):
             for j in range(pa.num_jobs):
-                hl = int(pa.job_helpers_len[j])
-                hs = pa.job_helpers[j, :hl].astype(np.int64)
-                tb.extend([b] * hl)
-                tslot.extend(j * N + hs)
+                hs = pa.job_helpers[j, :int(pa.job_helpers_len[j])]
+                hkey.append(b * S + j * N + hs.astype(np.int64))
+                rkey.append(b * S + j * N + int(pa.job_requestor[j]))
+        keys = np.concatenate(
+            [*hkey, fb * S + fsrc, fb * S + fdst, np.asarray(rkey, np.int64)])
+        _, first = np.unique(keys, return_index=True)
+        store_keys = keys[np.sort(first)]
+        sorter = np.argsort(store_keys)
+
+        def row_of(k: np.ndarray) -> np.ndarray:
+            at = np.searchsorted(store_keys, k, sorter=sorter)
+            return sorter[at].astype(np.int32)
+
+        src_row, dst_row = row_of(fb * S + fsrc), row_of(fb * S + fdst)
+        req_row = row_of(np.asarray(rkey, np.int64))
+        occupied = np.zeros(store_keys.size, dtype=bool)
+
+    # ---- init: one batched premultiply of every helper chunk
+    with spans.span("repro.dataplane.stage"):
+        tcoef, tdata = [], []
+        for b, pa in enumerate(pas):
+            for j in range(pa.num_jobs):
+                hs = pa.job_helpers[j, :int(pa.job_helpers_len[j])]
                 tcoef.extend(coeffs[b][j])
-                tdata.append(cws[b][block_maps[b][hs]])
-        staged = np.concatenate(tdata) if tb else None
-    if tb:
+                tdata.append(cws[b][block_maps[b][hs.astype(np.int64)]])
+        staged = np.concatenate(tdata) if tdata else None
+    pre = np.zeros((0, nbytes), dtype=np.uint8)
+    if tdata:
         with spans.span("repro.dataplane.premultiply"):
             pre = ops.gf256_scale_batch(
                 np.asarray(tcoef, dtype=np.uint8), staged,
                 use_kernel=use_kernel, interpret=interpret)
         del staged              # the staging copy must not outlive the call
-        pre = _pull(pre)
-        with spans.span("repro.dataplane.scatter"):
-            buf[np.asarray(tb), np.asarray(tslot)] = pre
-            occupied[np.asarray(tb), np.asarray(tslot)] = True
+    with spans.span("repro.dataplane.scatter"):
+        store = _grow(pre, store_keys.size)
+        occupied[:len(pre)] = True
+        del pre
 
     R = max((pa.num_rounds for pa in pas), default=0)
+    device_rounds = 0
     for r in range(R):
         with spans.span("repro.dataplane.gather"):
             rows = np.nonzero(fround == r)[0]
             if not rows.size:
                 continue
-            rb, rsrc, rdst = fb[rows], fsrc[rows], fdst[rows]
-            if not occupied[rb, rsrc].all():
-                bad = int(np.nonzero(~occupied[rb, rsrc])[0][0])
+            src = src_row[rows]
+            if not occupied[src].all():
+                bad = rows[int(np.nonzero(~occupied[src])[0][0])]
                 raise ValueError(
-                    f"round {r}: case {int(rb[bad])} transfer sources slot "
-                    f"(job {int(rsrc[bad]) // N}, node {int(rsrc[bad]) % N}) "
+                    f"round {r}: case {int(fb[bad])} transfer sources slot "
+                    f"(job {int(fsrc[bad]) // N}, node {int(fsrc[bad]) % N}) "
                     "which holds no buffer — consumed in an earlier round? "
                     "execute_plans_batch requires a validate_plan-clean plan")
-            payload = buf[rb, rsrc]                  # gather (T_r, nbytes)
-            buf[rb, rsrc] = 0                        # two-phase consume
-            occupied[rb, rsrc] = False
-            # fan-in groups per (case, destination slot), transfer order kept
-            key = rb * S + rdst
-            order = np.argsort(key, kind="stable")
-            skey = key[order]
+            payload = _take(store, src, nbytes)      # gather (T_r, nbytes)
+            occupied[src] = False                    # two-phase consume
+            # fan-in groups per destination row, transfer order kept
+            dst = dst_row[rows]
+            order = np.argsort(dst, kind="stable")
+            sdst = dst[order]
             boundary = np.empty(order.size, dtype=bool)
             boundary[0] = True
-            np.not_equal(skey[1:], skey[:-1], out=boundary[1:])
+            np.not_equal(sdst[1:], sdst[:-1], out=boundary[1:])
             starts = np.nonzero(boundary)[0]
             counts = np.diff(np.append(starts, order.size))
             groups = np.full((starts.size, int(counts.max())), -1,
@@ -266,25 +366,29 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
         with spans.span("repro.dataplane.fold"):
             folded = ops.xor_reduce_segments(
                 payload, groups, use_kernel=use_kernel, interpret=interpret)
-        folded = _pull(folded)
         with spans.span("repro.dataplane.accumulate"):
-            gkey = skey[starts]
-            gb, gs = gkey // S, gkey % S
-            buf[gb, gs] ^= folded                    # zeros when unoccupied
-            occupied[gb, gs] = True
+            gdst = sdst[starts]
+            store = _fold_in(store, src, gdst, folded)
+            occupied[gdst] = True
+        device_rounds += isinstance(store, jax.Array)
+    spans.count("device_rounds", device_rounds)
 
-    # ---- verify every job's requestor buffer against the lost block
+    # ---- one copy back: every job's requestor row
+    with spans.span("repro.dataplane.gather"):
+        rebuilt = _take(store, req_row, nbytes)
+    rebuilt = _pull(rebuilt)
     with spans.span("repro.dataplane.verify"):
         recon: list[dict[int, np.ndarray]] = [dict() for _ in range(B)]
         verified = np.ones(B, dtype=bool)
+        i = 0
         for b, pa in enumerate(pas):
             for j in range(pa.num_jobs):
-                slot = j * N + int(pa.job_requestor[j])
-                got = buf[b, slot].copy()
+                got = rebuilt[i]
                 recon[b][int(pa.job_id[j])] = got
                 fblock = int(block_maps[b][pa.job_failed[j]])
-                if not (occupied[b, slot]
+                if not (occupied[req_row[i]]
                         and np.array_equal(got, cws[b][fblock])):
                     verified[b] = False
+                i += 1
     return BatchExecutionResult(reconstructed=recon, verified=verified,
                                 bytes_moved=bytes_moved)

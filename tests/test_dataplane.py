@@ -119,6 +119,47 @@ def test_kernel_interpret_path_matches_ref(rng):
                                   ker.reconstructed[b][jid])
 
 
+@pytest.mark.parametrize("placed", (False, True), ids=("simulator", "placed"))
+@pytest.mark.parametrize("scheme", SINGLE + MULTI)
+def test_kernel_path_byte_identical(scheme, placed, rng):
+    """The kernel path, whose round state lives on the device (the
+    Pallas interpreter here), equals the numpy ref path and the serial
+    oracle on three stripes of every scheme, simulator-placed or placed
+    by `place_stripes`."""
+    n, k, failed, seed = ((7, 4, (1, 5), 9) if scheme in MULTI
+                          else (6, 3, (2,), 4))
+    cluster = 12
+    code = RSCode(n, k)
+    plan = compile_plan(_plan_for(_scenario(n, k, failed, seed=seed,
+                                            cluster=cluster), scheme, seed))
+    if placed:
+        stripes = place_stripes(3, code, cluster)
+        plans = [relabel_plan_nodes(plan, s.perm(cluster)) for s in stripes]
+        bmaps = [s.block_map(cluster) for s in stripes]
+    else:
+        plans, bmaps = [plan] * 3, None
+    cws = [code.encode(rng.integers(0, 256, size=(k, 200), dtype=np.uint8))
+           for _ in plans]
+    ref = execute_plans_batch(plans, code, cws, block_of=bmaps,
+                              use_kernel=False)
+    ker = execute_plans_batch(plans, code, cws, block_of=bmaps,
+                              use_kernel=True)
+    assert ref.all_verified and ker.all_verified
+    assert np.array_equal(ref.bytes_moved, ker.bytes_moved)
+    for c, pa in enumerate(plans):
+        ser = executor.execute_plan(
+            decompile(pa), code, cws[c], use_kernel=False,
+            block_of=None if bmaps is None else bmaps[c])
+        assert ser.verified and int(ker.bytes_moved[c]) == ser.bytes_moved
+        assert (ker.reconstructed[c].keys() == ref.reconstructed[c].keys()
+                == ser.reconstructed.keys())
+        for jid, blk in ser.reconstructed.items():
+            assert np.array_equal(ker.reconstructed[c][jid], np.asarray(blk))
+            assert np.array_equal(ref.reconstructed[c][jid], np.asarray(blk))
+        for j, f in enumerate(failed):
+            assert np.array_equal(ker.reconstructed[c][j], cws[c][f])
+
+
 # -------------------------------------------------------- hypothesis sweep
 try:
     from hypothesis import given, settings, strategies as st
@@ -244,6 +285,46 @@ def test_batched_consumed_source_raises(rng):
     cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
     with pytest.raises(ValueError, match="holds no buffer"):
         execute_plans_batch([bad], [code], [cw], use_kernel=False)
+
+
+def test_batched_consumed_source_raises_on_kernel_path(rng):
+    """The same refusal where the round state lives on the device: the
+    source check runs on the host's occupancy before any gather."""
+    jobs = [Job(job_id=0, failed_node=0, requestor=0, helpers=(1, 2))]
+    bad = RepairPlan(jobs=jobs, rounds=[
+        Round(transfers=[Transfer(src=1, dst=0, job=0,
+                                  terms=frozenset({1}))]),
+        Round(transfers=[Transfer(src=1, dst=0, job=0,
+                                  terms=frozenset({1}))]),   # 1 already sent
+    ])
+    code = RSCode(4, 2)
+    cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
+    with pytest.raises(ValueError, match=r"round 1: case 0 transfer sources "
+                       r"slot \(job 0, node 1\) which holds no buffer"):
+        execute_plans_batch([bad], [code], [cw], use_kernel=True)
+
+
+@pytest.mark.parametrize("use_kernel", (False, True), ids=("ref", "kernel"))
+def test_consumed_buffer_refilled_later(use_kernel, rng):
+    """A node that sends its buffer and receives again in a later round
+    starts from an empty buffer: the consume must clear the old bytes on
+    both paths, or the requestor ends with the wrong block."""
+    jobs = [Job(job_id=0, failed_node=0, requestor=0, helpers=(1, 2))]
+    plan = RepairPlan(jobs=jobs, rounds=[
+        Round(transfers=[Transfer(src=1, dst=2, job=0,
+                                  terms=frozenset({1}))]),
+        Round(transfers=[Transfer(src=2, dst=1, job=0,
+                                  terms=frozenset({1, 2}))]),
+        Round(transfers=[Transfer(src=1, dst=0, job=0,
+                                  terms=frozenset({1, 2}))]),
+    ])
+    validate_plan(plan)
+    code = RSCode(4, 2)
+    cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
+    ser = executor.execute_plan(plan, code, cw, use_kernel=False)
+    bat = execute_plans_batch([plan], [code], [cw], use_kernel=use_kernel)
+    assert ser.verified and bat.all_verified
+    assert np.array_equal(bat.reconstructed[0][0], cw[0])
 
 
 def test_batched_incomplete_plan_not_verified(rng):

@@ -23,6 +23,7 @@ from repro.core.engine.dataplane import execute_plans_batch
 from repro.core.simulator import Scenario
 from repro.ec.rs import RSCode
 from repro.ec.stripe import place_stripes
+from repro.kernels import ops
 from repro.sim.sweep import _verify_plan
 
 METRICS = Path(__file__).resolve().parents[1] / "bench" / "metrics"
@@ -100,15 +101,15 @@ def _inside(ev, outer) -> bool:
 
 
 def _d2h_bytes(b: dict) -> int:
-    """(premultiply rows + fold groups of every round) x cell bytes."""
-    rows = groups = 0
-    for pa in b["plans"]:
-        rows += int(pa.job_helpers_len.sum())
-        for r in range(pa.num_rounds):
-            sl = pa.round_rows(r)
-            groups += len(set(zip(pa.t_job_idx[sl].tolist(),
-                                  pa.t_dst[sl].tolist())))
-    return (rows + groups) * NBYTES
+    """One requestor row per job x cell bytes: the round state stays on
+    the device, and only the rebuilt blocks come back."""
+    return sum(pa.num_jobs for pa in b["plans"]) * NBYTES
+
+
+def _rounds_run(b: dict) -> int:
+    """Rounds in which some plan of the batch has a transfer."""
+    return len({r for pa in b["plans"] for r in range(pa.num_rounds)
+                if len(pa.t_src[pa.round_rows(r)])})
 
 
 def _lost_bytes(b: dict) -> int:
@@ -175,7 +176,8 @@ def test_d2h_bytes_count_every_result(traced):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_d2h_bytes_zero_on_numpy_path(kind, tmp_path):
-    """The numpy ref path hands back host arrays: nothing is copied."""
+    """The numpy ref path hands back host arrays: nothing is copied, and
+    no round's state lives on the device."""
     b = _batch(kind)
     before = spans.totals()
     with jax.profiler.trace(str(tmp_path)):
@@ -184,6 +186,54 @@ def test_d2h_bytes_zero_on_numpy_path(kind, tmp_path):
     after = spans.totals()
     assert _delta(before, after, "repro.dataplane.d2h", "count") > 0
     assert _delta(before, after, "repro.dataplane.d2h", "bytes") == 0
+    assert _delta(before, after, BATCH, "device_rounds") == 0
+
+
+def test_device_rounds_count_every_round_on_kernel_path(traced):
+    rounds = _rounds_run(traced.b)
+    assert rounds > 0
+    assert _delta(traced.before, traced.after, BATCH,
+                  "device_rounds") == rounds
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_gf_steps_get_host_chunks_and_round_payloads(kind, monkeypatch):
+    """The premultiply gets the (M, nbytes) helper chunks from host
+    memory; each fold gets exactly its round's (T_r, nbytes) payload,
+    on the device, and its (G_r, Kmax) groups: the byte counts the
+    benchmark's rooflines take from these arguments stay the work done."""
+    b = _batch(kind)
+    seen = []
+
+    def spy(name):
+        orig = getattr(ops, name)
+
+        def inner(x, y, **kw):
+            seen.append((name, x, y))
+            return orig(x, y, **kw)
+        return inner
+
+    for name in ("gf256_scale_batch", "xor_reduce_segments"):
+        monkeypatch.setattr(ops, name, spy(name))
+    res = _run(b)
+    assert res.all_verified
+    (pname, coeffs, chunks), *folds = seen
+    helpers = sum(int(pa.job_helpers_len.sum()) for pa in b["plans"])
+    assert pname == "gf256_scale_batch"
+    assert isinstance(chunks, np.ndarray)
+    assert chunks.shape == (helpers, NBYTES) and coeffs.shape == (helpers,)
+    assert len(folds) == _rounds_run(b)
+    for r, (fname, payload, groups) in enumerate(folds):
+        sl = [pa.round_rows(r) for pa in b["plans"]]
+        t_r = sum(len(pa.t_src[s]) for pa, s in zip(b["plans"], sl))
+        g_r = sum(len(set(zip(pa.t_job_idx[s].tolist(),
+                              pa.t_dst[s].tolist())))
+                  for pa, s in zip(b["plans"], sl))
+        assert fname == "xor_reduce_segments"
+        assert isinstance(payload, jax.Array)
+        assert payload.shape == (t_r, NBYTES)
+        assert groups.shape[0] == g_r
+        assert sorted(groups[groups >= 0].tolist()) == list(range(t_r))
 
 
 def test_profiler_off_records_nothing_and_changes_nothing(traced):

@@ -1,4 +1,5 @@
-"""Compile the data plane's Pallas kernels for a described TPU v5e chip.
+"""Compile the data plane's Pallas kernels, and the programs that keep its
+round state on the device, for a described TPU v5e chip.
 
 Nothing runs: each kernel is lowered and compiled at a 1 MiB cell width
 for one chip of a `v5e:2x2` topology that the installed TPU compiler
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.engine import dataplane
 from repro.kernels.gf256_matmul import gf256_matmul_planes, gf256_scale_planes
 from repro.kernels.xor_reduce import xor_reduce_groups_words, xor_reduce_words
 
@@ -73,3 +75,32 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
             for s, dt in shapes]
     compiled = fn.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The store programs at the shapes of one `hdfs-rs-6-3-1024k.repair`
+# batch: 56 rows of 1 MiB, 48 of them premultiplied, and a 24-row round.
+ROWS, PRE, ROUND = 56, 48, 24
+STORE_PROGRAMS = {
+    "grow": (dataplane._grow_device, [((PRE, CELL), jnp.uint8)], (ROWS,)),
+    "take": (dataplane._take_device, [
+        ((ROWS, CELL // 128, 128), jnp.uint8), ((ROUND,), jnp.int32)],
+        (CELL,)),
+    "fold_in": (dataplane._fold_in_device, [
+        ((ROWS, CELL // 128, 128), jnp.uint8), ((ROUND,), jnp.int32),
+        ((ROUND,), jnp.int32), ((ROUND, CELL), jnp.uint8)], ()),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STORE_PROGRAMS))
+def test_store_program_compiles_small_for_v5e(name, one_chip,
+                                              no_compile_cache):
+    """Row by row, each program stays well under a megabyte of code (a
+    gather or scatter of whole uint8 rows compiles to 10-22 MB here), and
+    the consume-and-accumulate writes the donated store in place."""
+    fn, shapes, static = STORE_PROGRAMS[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    mem = fn.lower(*args, *static).compile().memory_analysis()
+    assert mem.generated_code_size_in_bytes < 1 << 20
+    if name == "fold_in":
+        assert mem.alias_size_in_bytes == ROWS * CELL
