@@ -321,6 +321,7 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
                 tcoef.extend(coeffs[b][j])
                 tdata.append(cws[b][block_maps[b][hs.astype(np.int64)]])
         staged = np.concatenate(tdata) if tdata else None
+        spans.count("helper_bytes", 0 if staged is None else staged.nbytes)
     pre = np.zeros((0, nbytes), dtype=np.uint8)
     if tdata:
         with spans.span("repro.dataplane.premultiply"):
@@ -336,42 +337,48 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
     R = max((pa.num_rounds for pa in pas), default=0)
     device_rounds = 0
     for r in range(R):
-        with spans.span("repro.dataplane.gather"):
-            rows = np.nonzero(fround == r)[0]
-            if not rows.size:
-                continue
-            src = src_row[rows]
-            if not occupied[src].all():
-                bad = rows[int(np.nonzero(~occupied[src])[0][0])]
-                raise ValueError(
-                    f"round {r}: case {int(fb[bad])} transfer sources slot "
-                    f"(job {int(fsrc[bad]) // N}, node {int(fsrc[bad]) % N}) "
-                    "which holds no buffer — consumed in an earlier round? "
-                    "execute_plans_batch requires a validate_plan-clean plan")
-            payload = _take(store, src, nbytes)      # gather (T_r, nbytes)
-            occupied[src] = False                    # two-phase consume
-            # fan-in groups per destination row, transfer order kept
-            dst = dst_row[rows]
-            order = np.argsort(dst, kind="stable")
-            sdst = dst[order]
-            boundary = np.empty(order.size, dtype=bool)
-            boundary[0] = True
-            np.not_equal(sdst[1:], sdst[:-1], out=boundary[1:])
-            starts = np.nonzero(boundary)[0]
-            counts = np.diff(np.append(starts, order.size))
-            groups = np.full((starts.size, int(counts.max())), -1,
-                             dtype=np.int64)
-            pos = np.arange(order.size) - np.repeat(starts, counts)
-            groups[np.repeat(np.arange(starts.size), counts), pos] = order
-        with spans.span("repro.dataplane.fold"):
-            folded = ops.xor_reduce_segments(
-                payload, groups, use_kernel=use_kernel, interpret=interpret)
-        with spans.span("repro.dataplane.accumulate"):
-            gdst = sdst[starts]
-            store = _fold_in(store, src, gdst, folded)
-            occupied[gdst] = True
-        device_rounds += isinstance(store, jax.Array)
+        with spans.span("repro.dataplane.round"):
+            with spans.span("repro.dataplane.gather"):
+                rows = np.nonzero(fround == r)[0]
+                if not rows.size:
+                    continue
+                src = src_row[rows]
+                if not occupied[src].all():
+                    bad = rows[int(np.nonzero(~occupied[src])[0][0])]
+                    job, node = divmod(int(fsrc[bad]), N)
+                    raise ValueError(
+                        f"round {r}: case {int(fb[bad])} transfer sources "
+                        f"slot (job {job}, node {node}) which holds no "
+                        "buffer — consumed in an earlier round? "
+                        "execute_plans_batch requires a validate_plan-clean "
+                        "plan")
+                payload = _take(store, src, nbytes)  # gather (T_r, nbytes)
+                occupied[src] = False                # two-phase consume
+                # fan-in groups per destination row, transfer order kept
+                dst = dst_row[rows]
+                order = np.argsort(dst, kind="stable")
+                sdst = dst[order]
+                boundary = np.empty(order.size, dtype=bool)
+                boundary[0] = True
+                np.not_equal(sdst[1:], sdst[:-1], out=boundary[1:])
+                starts = np.nonzero(boundary)[0]
+                counts = np.diff(np.append(starts, order.size))
+                groups = np.full((starts.size, int(counts.max())), -1,
+                                 dtype=np.int64)
+                pos = np.arange(order.size) - np.repeat(starts, counts)
+                groups[np.repeat(np.arange(starts.size), counts), pos] = order
+            with spans.span("repro.dataplane.fold"):
+                folded = ops.xor_reduce_segments(
+                    payload, groups, use_kernel=use_kernel,
+                    interpret=interpret)
+            with spans.span("repro.dataplane.accumulate"):
+                gdst = sdst[starts]
+                store = _fold_in(store, src, gdst, folded)
+                occupied[gdst] = True
+            device_rounds += isinstance(store, jax.Array)
     spans.count("device_rounds", device_rounds)
+    spans.count("rounds", R)
+    spans.count("jobs", req_row.size)
 
     # ---- one copy back: every job's requestor row
     with spans.span("repro.dataplane.gather"):

@@ -29,38 +29,53 @@ from repro.sim.sweep import _verify_plan
 METRICS = Path(__file__).resolve().parents[1] / "bench" / "metrics"
 NBYTES = 256
 BATCH = "repro.dataplane.batch"
-STEPS = ("prepare", "stage", "premultiply", "d2h", "scatter", "gather",
-         "fold", "accumulate", "verify")
+ROUND = "repro.dataplane.round"
+GATHER = "repro.dataplane.gather"
+STEPS = ("prepare", "stage", "premultiply", "d2h", "scatter", "round",
+         "gather", "fold", "accumulate", "verify")
 NAMES = (BATCH,) + tuple(f"repro.dataplane.{s}" for s in STEPS)
+# what one round of the round loop holds
+ROUND_STEPS = tuple(f"repro.dataplane.{s}"
+                    for s in ("gather", "fold", "accumulate"))
 READERS = ("dataplane_host_ms_per_lost_MiB.repair",
            "dataplane_copy_back_ms_per_lost_MiB.repair",
            "dataplane_gf_call_ms_per_lost_MiB.repair",
-           "device_to_host_bytes_per_lost_byte.repair")
+           "device_to_host_bytes_per_lost_byte.repair",
+           "dataplane_ms_per_round.repair",
+           "dataplane_stage_ms_per_helper_MiB.repair")
 
 
 def _batch(kind: str) -> dict:
-    """Three stripes: placed RS(9,6) with one lost block under BMF, or
-    RS(14,10) with two lost blocks under MSRepair (simulator placement)."""
-    n, k, failed, scheme, cluster, placed = {
-        "rs96_bmf_placed": (9, 6, (2,), "bmf", 12, True),
-        "rs1410_msrepair": (14, 10, (1, 5), "msrepair", 16, False),
+    """Three stripes: placed RS(9,6) with one lost block under BMF;
+    RS(14,10) with two lost blocks under MSRepair (simulator placement);
+    or placed RS(14,10) mixing BMF singles with an MSRepair double, as a
+    batch of the Facebook-warehouse cell holds, so its plans differ in
+    jobs and rounds."""
+    n, k, cluster, placed, stripes = {
+        "rs96_bmf_placed": (9, 6, 12, True, [((2,), "bmf")] * 3),
+        "rs1410_msrepair": (14, 10, 16, False, [((1, 5), "msrepair")] * 3),
+        "rs1410_mixed_placed": (14, 10, 16, True, [
+            ((2,), "bmf"), ((1, 5), "msrepair"), ((7,), "bmf")]),
     }[kind]
     code = RSCode(n, k)
     rng = np.random.default_rng(n)
     m = topology.heterogeneous_matrix(cluster, low=3, high=30, seed=n)
-    sc = Scenario(num_nodes=cluster, code=code, failed=failed,
-                  bw=BandwidthProcess(base=m, change_interval=2.0, seed=n,
-                                      mode="markov"),
-                  ingress=IngressModel(seed=n), chunk_mb=4.0)
-    plan = compile_plan(_verify_plan(sc, scheme, n, bmf_optimize_all=False))
+    plans = []
+    for failed, scheme in stripes:
+        sc = Scenario(num_nodes=cluster, code=code, failed=failed,
+                      bw=BandwidthProcess(base=m, change_interval=2.0,
+                                          seed=n, mode="markov"),
+                      ingress=IngressModel(seed=n), chunk_mb=4.0)
+        plans.append(compile_plan(
+            _verify_plan(sc, scheme, n, bmf_optimize_all=False)))
     cws = [code.encode(rng.integers(0, 256, size=(k, NBYTES), dtype=np.uint8))
-           for _ in range(3)]
+           for _ in stripes]
+    bmaps = None
     if placed:
-        stripes = place_stripes(3, code, cluster)
-        plans = [relabel_plan_nodes(plan, s.perm(cluster)) for s in stripes]
-        bmaps = [s.block_map(cluster) for s in stripes]
-    else:
-        plans, bmaps = [plan] * 3, None
+        placed = place_stripes(len(stripes), code, cluster)
+        plans = [relabel_plan_nodes(pa, s.perm(cluster))
+                 for pa, s in zip(plans, placed)]
+        bmaps = [s.block_map(cluster) for s in placed]
     return dict(plans=plans, code=code, cws=cws, block_of=bmaps)
 
 
@@ -116,6 +131,10 @@ def _lost_bytes(b: dict) -> int:
     return NBYTES * sum(pa.num_jobs for pa in b["plans"])
 
 
+def _helper_bytes(b: dict) -> int:
+    return NBYTES * sum(int(pa.job_helpers_len.sum()) for pa in b["plans"])
+
+
 def _reader(name: str):
     spec = importlib.util.spec_from_file_location(
         "reader_" + name.replace(".", "_"), METRICS / f"{name}.py")
@@ -124,7 +143,7 @@ def _reader(name: str):
     return mod
 
 
-KINDS = ("rs96_bmf_placed", "rs1410_msrepair")
+KINDS = ("rs96_bmf_placed", "rs1410_msrepair", "rs1410_mixed_placed")
 
 
 @pytest.fixture(scope="module", params=KINDS)
@@ -141,14 +160,31 @@ def test_steps_nest_in_batch_nest_in_caller(traced):
     events = traced.events
     callers = [e for e in events if e[1] == "caller"]
     batches = [e for e in events if e[1] == BATCH]
+    rounds = [e for e in events if e[1] == ROUND]
     assert len(callers) == 1 and len(batches) == 1
     assert _inside(batches[0], callers[0])
     for e in events:
         if e[1] not in ("caller", BATCH):
             assert _inside(e, batches[0]), e[1]
-            # steps are siblings: none holds another (d2h is no child of
+        if e[1] not in ("caller", BATCH, ROUND):
+            # steps are leaves: none holds another (d2h is no child of
             # the GF call before it)
             assert not any(_inside(o, e) for o in events), e[1]
+            # a round holds its gather, fold and accumulate; the other
+            # steps are siblings of the rounds
+            held = sum(_inside(e, r) for r in rounds)
+            if e[1] in ROUND_STEPS[1:]:
+                assert held == 1, e[1]
+            elif e[1] != GATHER:
+                assert held == 0, e[1]
+    for r in rounds:
+        inside = sorted(o[1] for o in events if _inside(o, r))
+        # an empty round stops in its gather
+        assert inside in ([GATHER], sorted(ROUND_STEPS)), inside
+    # after the last round, one gather takes the requestor rows
+    (last,) = [e for e in events if e[1] == GATHER
+               and not any(_inside(e, r) for r in rounds)]
+    assert all(r[3] <= last[2] for r in rounds)
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -162,8 +198,9 @@ def test_trace_self_time_matches_totals(traced, name):
     events = traced.events
     self_ns = 0
     for e in (e for e in events if e[1] == name):
-        children = [o for o in events
-                    if o[1].startswith("repro.") and _inside(o, e)]
+        inner = [o for o in events
+                 if o[1].startswith("repro.") and _inside(o, e)]
+        children = [o for o in inner if not any(_inside(o, p) for p in inner)]
         self_ns += (e[3] - e[2]) - sum(o[3] - o[2] for o in children)
     recorded = _delta(traced.before, traced.after, name, "self_s")
     assert abs(self_ns * 1e-9 - recorded) <= max(1e-3, 0.05 * recorded)
@@ -196,6 +233,21 @@ def test_device_rounds_count_every_round_on_kernel_path(traced):
                   "device_rounds") == rounds
 
 
+def test_batch_counts_jobs_rounds_and_helper_bytes(traced):
+    """`jobs` is the lost blocks rebuilt, `rounds` the batch's longest
+    plan, one `round` span each (an empty round too), and `helper_bytes`
+    the chunks staged for the premultiply."""
+    b = traced.b
+
+    def got(name, key):
+        return _delta(traced.before, traced.after, name, key)
+
+    assert got(BATCH, "jobs") == sum(pa.num_jobs for pa in b["plans"])
+    assert got(BATCH, "rounds") == got(ROUND, "count") == max(
+        pa.num_rounds for pa in b["plans"])
+    assert got("repro.dataplane.stage", "helper_bytes") == _helper_bytes(b)
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_gf_steps_get_host_chunks_and_round_payloads(kind, monkeypatch):
     """The premultiply gets the (M, nbytes) helper chunks from host
@@ -224,7 +276,8 @@ def test_gf_steps_get_host_chunks_and_round_payloads(kind, monkeypatch):
     assert chunks.shape == (helpers, NBYTES) and coeffs.shape == (helpers,)
     assert len(folds) == _rounds_run(b)
     for r, (fname, payload, groups) in enumerate(folds):
-        sl = [pa.round_rows(r) for pa in b["plans"]]
+        sl = [pa.round_rows(r) if r < pa.num_rounds else slice(0, 0)
+              for pa in b["plans"]]
         t_r = sum(len(pa.t_src[s]) for pa, s in zip(b["plans"], sl))
         g_r = sum(len(set(zip(pa.t_job_idx[s].tolist(),
                               pa.t_dst[s].tolist())))
@@ -303,6 +356,9 @@ def test_reader_reads_the_window(kind, reader, monkeypatch, tmp_path):
         READERS[2]: 1e3 * sum(t[f"repro.dataplane.{s}"]["total_s"]
                               for s in ("premultiply", "fold")) / mib,
         READERS[3]: _d2h_bytes(b) / lost,
+        READERS[4]: 1e3 * t[ROUND]["total_s"] / t[ROUND]["count"],
+        READERS[5]: 1e3 * t["repro.dataplane.stage"]["self_s"]
+        / (_helper_bytes(b) / 2**20),
     }[reader]
     ctx = types.SimpleNamespace(calls=[], trace=None, e2e={},
                                 lost_bytes=lost, peak=None)
@@ -323,4 +379,18 @@ def test_reader_returns_none_without_spans(reader, case, monkeypatch):
     ctx = types.SimpleNamespace(
         calls=[], trace=None, e2e={}, peak=None,
         lost_bytes=0 if case == "no_lost_bytes" else 1024)
+    assert _reader(reader).read(ctx) is None
+
+
+@pytest.mark.parametrize("reader", READERS[4:])
+def test_reader_returns_none_without_its_own_spans(reader, monkeypatch):
+    """A program that has the batch span and the steps before rounds and
+    staged bytes were recorded (as before the `round` span and the
+    `helper_bytes` counter) reads as nothing, not as zero."""
+    monkeypatch.setattr(spans, "_totals", {
+        BATCH: {"count": 1, "total_ns": 10, "self_ns": 1},
+        **{f"repro.dataplane.{s}": {"count": 1, "total_ns": 1, "self_ns": 1}
+           for s in STEPS if s != "round"}})
+    ctx = types.SimpleNamespace(calls=[], trace=None, e2e={}, peak=None,
+                                lost_bytes=1024)
     assert _reader(reader).read(ctx) is None
