@@ -16,7 +16,7 @@ relabeled through its placement), and the same byte workload runs twice:
 Two paths each: the **ref** (non-interpret) path — numpy oracles batched
 vs per-chunk jnp calls, the honest CPU-throughput number CI gates at
 >= 3x batched-vs-serial on a >= 64-stripe batch — and the **kernel**
-path, which runs the exact Pallas kernel bodies (`gf256_scale_planes` /
+path, which runs the Pallas kernels (`gf256_scale_rows` /
 `xor_reduce_groups_words` grids vs one `pallas_call` per chunk). Off TPU
 the kernels run in the interpreter on a small slice (`*_kernel_interpret`
 rows): a correctness path, not a performance proxy, so that split is

@@ -19,15 +19,17 @@ j — and executes every round as three array steps:
    `kernels.ops.xor_reduce_segments` call; one update consumes the
    round's sources and XORs the folded rows into their destinations.
 
-On TPU the two ops drive the Pallas kernel bodies over a grid (one
-`pallas_call` per step instead of one per chunk); everywhere else they
-fall back to the numpy oracles in `repro.kernels.ref`, so the batched
-path stays a genuine throughput win on CPU too (`benchmarks/
-bench_dataplane.py` gates it). The store follows the premultiply's
-answer: a device array on the kernel path, where the round state stays
-on the device and only the rebuilt blocks come back in one copy, and a
-host array, updated in place, on the ref path. The host keeps only the
-index plan and the rows' occupancy.
+On TPU the two ops run Pallas kernels over a grid (one `pallas_call`
+per step instead of one per chunk): the premultiply scales the staged
+bytes as they are, by shift-and-add over GF(256)'s polynomial on uint32
+words (`kernels.gf256_scale`, no bit planes), and the fold XORs words;
+everywhere else they fall back to the numpy oracles in
+`repro.kernels.ref`, so the batched path stays a genuine throughput win
+on CPU too (`benchmarks/bench_dataplane.py` gates it). The store
+follows the premultiply's answer: a device array on the kernel path,
+where the round state stays on the device and only the rebuilt blocks
+come back in one copy, and a host array, updated in place, on the ref
+path. The host keeps only the index plan and the rows' occupancy.
 
 Execution semantics match the serial oracle exactly: within a round all
 sources are consumed before any arrival lands (store-and-forward

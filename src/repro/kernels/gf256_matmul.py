@@ -68,43 +68,3 @@ def gf256_matmul_planes(
         interpret=interpret,
     )(masks, planes)
     return out[:, :, :w]
-
-
-@functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
-def gf256_scale_planes(
-    masks: jax.Array,
-    planes: jax.Array,
-    *,
-    block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = True,
-) -> jax.Array:
-    """(M,1,8,8) u32 masks x (M,8,W) u32 planes -> (M,8,W) u32 planes.
-
-    The batched data-plane premultiply: row r is scaled by its *own*
-    coefficient mask (elementwise over rows, not an (m, k) contraction).
-    Same kernel body as `gf256_matmul_planes` (`_kernel` with k=1), driven
-    over an (M, W/block_w) grid — one `pallas_call` covers every
-    (job, helper) chunk of a whole plan batch instead of one call per
-    chunk.
-    """
-    m = masks.shape[0]
-    assert masks.shape[1:] == (1, 8, 8), masks.shape
-    mm, eight, w = planes.shape
-    assert mm == m and eight == 8, (masks.shape, planes.shape)
-    w_pad = -w % block_w
-    if w_pad:
-        planes = jnp.pad(planes, ((0, 0), (0, 0), (0, w_pad)))
-    wp = planes.shape[-1]
-    grid = (m, wp // block_w)
-    out = pl.pallas_call(
-        functools.partial(_kernel, k=1),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 8, 8), lambda r, t: (r, 0, 0, 0)),
-            pl.BlockSpec((1, 8, block_w), lambda r, t: (r, 0, t)),
-        ],
-        out_specs=pl.BlockSpec((1, 8, block_w), lambda r, t: (r, 0, t)),
-        out_shape=jax.ShapeDtypeStruct((m, 8, wp), jnp.uint32),
-        interpret=interpret,
-    )(masks, planes)
-    return out[:, :, :w]

@@ -17,7 +17,8 @@ import numpy as np
 
 from repro.ec import bitplane
 from repro.kernels import ref
-from repro.kernels.gf256_matmul import gf256_matmul_planes, gf256_scale_planes
+from repro.kernels.gf256_matmul import gf256_matmul_planes
+from repro.kernels.gf256_scale import gf256_scale_rows
 from repro.kernels.xor_reduce import xor_reduce_groups_words, xor_reduce_words
 
 
@@ -107,20 +108,17 @@ def gf256_scale_batch(
     (job, helper) chunk of a plan batch. On TPU it runs the compiled
     Pallas kernel; elsewhere `use_kernel=None` (the default) takes the
     numpy oracle (`ref.gf256_scale_batch_np`), see `_batched_path`. The
-    kernel path drives `gf256_scale_planes` over an (M, W/block) grid —
-    the same kernel body as `gf256_matmul`, one grid row per chunk
-    instead of one `pallas_call` per chunk. Returns numpy on the ref
-    path, a device array on the kernel path.
+    kernel path is one jitted program, `gf256_scale_rows`: the host
+    `data` goes up as it is and the kernel scales its bytes directly, by
+    shift-and-add over 0x11D on uint32 words, with per-row bit masks
+    made on the device from `coeffs`. Returns numpy on the ref path, an
+    (M, nbytes) uint8 device array on the kernel path.
     """
     coeffs = np.asarray(coeffs, dtype=np.uint8).reshape(-1)
     use_kernel, interpret = _batched_path(use_kernel, interpret)
     if coeffs.size == 0 or not use_kernel:
         return ref.gf256_scale_batch_np(coeffs, np.asarray(data))
-    nbytes = data.shape[-1]
-    masks = jnp.asarray(bitplane.coeff_to_masks_np(coeffs[:, None]))
-    planes = bitplane.pack_jnp(jnp.asarray(data))
-    out_planes = gf256_scale_planes(masks, planes, interpret=interpret)
-    return bitplane.unpack_jnp(out_planes, nbytes)
+    return gf256_scale_rows(coeffs, data, interpret=interpret)
 
 
 def xor_reduce_segments(

@@ -6,13 +6,14 @@ Separate from tests/test_kernels.py and tests/test_rs.py on purpose:
 those modules skip entirely without hypothesis, while everything here is
 deterministic and must run on the bare-numpy tier-1 environment too.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.ec import gf256
 from repro.ec.rs import RSCode
-from repro.kernels import ops
+from repro.kernels import gf256_scale, ops
 
 
 # ------------------------------------------------------- gf256_scale_batch
@@ -37,6 +38,38 @@ def test_gf256_scale_batch_paths(m, nbytes, rng):
         for i in range(m)
     ])
     assert np.array_equal(per_row, want)
+
+
+@pytest.mark.parametrize("nbytes", [1, 33, 1000, 4100])
+@pytest.mark.parametrize("m", [1, 256])
+def test_gf256_scale_batch_kernel_byte_domain(m, nbytes, rng):
+    """The kernel path (interpret) scales packed bytes by shift-and-add:
+    every coefficient against `MUL_TABLE` in one call when M = 256, at
+    widths that are no multiple of a word, a lane or a block, and as one
+    row (31 pad rows of its row block). It returns an (M, nbytes) uint8
+    device array."""
+    coeffs = (np.arange(256, dtype=np.uint8) if m == 256
+              else rng.integers(0, 256, size=m, dtype=np.uint8))
+    data = rng.integers(0, 256, size=(m, nbytes), dtype=np.uint8)
+    got = ops.gf256_scale_batch(coeffs, data, use_kernel=True,
+                                interpret=True)
+    assert isinstance(got, jax.Array)
+    assert got.shape == (m, nbytes) and got.dtype == jnp.uint8
+    assert np.array_equal(np.asarray(got),
+                          gf256.MUL_TABLE[coeffs[:, None], data])
+
+
+@pytest.mark.parametrize("m,nbytes", [
+    (3, gf256_scale.BLOCK_W + 100),          # a partial second block
+    (40, 2 * gf256_scale.BLOCK_W)])          # two row blocks, two blocks
+def test_gf256_scale_rows_across_blocks(m, nbytes, rng):
+    """Rows longer than a block run over several grid steps, and every
+    step reads the masks of its own row block."""
+    coeffs = rng.integers(0, 256, size=m, dtype=np.uint8)
+    data = rng.integers(0, 256, size=(m, nbytes), dtype=np.uint8)
+    got = gf256_scale.gf256_scale_rows(coeffs, data, interpret=True)
+    assert np.array_equal(np.asarray(got),
+                          gf256.MUL_TABLE[coeffs[:, None], data])
 
 
 def test_gf256_scale_batch_zero_and_one_coeffs(rng):

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.engine import dataplane
-from repro.kernels.gf256_matmul import gf256_matmul_planes, gf256_scale_planes
+from repro.kernels.gf256_matmul import gf256_matmul_planes
+from repro.kernels.gf256_scale import gf256_scale_rows
 from repro.kernels.xor_reduce import xor_reduce_groups_words, xor_reduce_words
 
 CELL = 1 << 20                 # HDFS RS-6-3-1024k cell, bytes
@@ -54,27 +55,37 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-# (kernel, argument shapes and dtypes): a 64-stripe RS(6,3) batch of
-# 1 MiB cells — 192 premultiplied helper chunks, a 3x3 parity encode,
-# a 4-way fold and 64 three-member fan-in groups
+# (kernel, argument shapes and dtypes, rows of the premultiply or None):
+# the premultiply of one `hdfs-rs-6-3-1024k.repair` batch (48 helper
+# chunks of 1 MiB) and of the `fb-warehouse-rs-14-10.repair` batch with
+# its double failure (90), then a 64-stripe RS(6,3) batch of 1 MiB
+# cells — a 3x3 parity encode, a 4-way fold and 64 three-member fan-in
+# groups
 KERNELS = {
-    "gf256_scale_planes": (gf256_scale_planes, [
-        ((192, 1, 8, 8), jnp.uint32), ((192, 8, PLANE_W), jnp.uint32)]),
+    "gf256_scale_rows_hdfs": (gf256_scale_rows, [
+        ((48,), jnp.uint8), ((48, CELL), jnp.uint8)], 48),
+    "gf256_scale_rows_fb": (gf256_scale_rows, [
+        ((90,), jnp.uint8), ((90, CELL), jnp.uint8)], 90),
     "gf256_matmul_planes": (gf256_matmul_planes, [
-        ((3, 3, 8, 8), jnp.uint32), ((3, 8, PLANE_W), jnp.uint32)]),
-    "xor_reduce_words": (xor_reduce_words, [((4, WORDS), jnp.uint32)]),
+        ((3, 3, 8, 8), jnp.uint32), ((3, 8, PLANE_W), jnp.uint32)], None),
+    "xor_reduce_words": (xor_reduce_words, [((4, WORDS), jnp.uint32)], None),
     "xor_reduce_groups_words": (xor_reduce_groups_words, [
-        ((64, 3, WORDS), jnp.uint32)]),
+        ((64, 3, WORDS), jnp.uint32)], None),
 }
 
 
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
-    fn, shapes = KERNELS[name]
+    """Each kernel is a Mosaic kernel in its program. The premultiply
+    reads and writes its (M, 1 MiB) bytes in place: its scratch stays
+    below one copy of them, so no packed or relaid-out copy comes back."""
+    fn, shapes, rows = KERNELS[name]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     compiled = fn.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if rows is not None:
+        assert compiled.memory_analysis().temp_size_in_bytes < rows * CELL
 
 
 # The store programs at the shapes of one `hdfs-rs-6-3-1024k.repair`
