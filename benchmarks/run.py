@@ -18,8 +18,8 @@ def main() -> None:
     from repro.compile_cache import use_compile_cache
 
     use_compile_cache(Path(__file__).resolve().parent.parent)
-    from benchmarks import (bench_ablation, bench_aliyun, bench_dataplane,
-                            bench_fig8, bench_fig9, bench_fig10, bench_fig11,
+    from benchmarks import (bench_ablation, bench_aliyun, bench_fig8,
+                            bench_fig9, bench_fig10, bench_fig11,
                             bench_sweep, bench_table2)
     modules = [
         ("table2", bench_table2),
@@ -30,7 +30,6 @@ def main() -> None:
         ("aliyun", bench_aliyun),
         ("ablation", bench_ablation),
         ("sweep", bench_sweep),
-        ("dataplane", bench_dataplane),
     ]
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")

@@ -3,7 +3,8 @@
 Shows, for one failure on the measured Aliyun ECS matrix (paper Table III)
 under hot churn: the plans traditional / PPR / PPT / BMFRepair produce,
 their simulated repair times, and the actual byte-verified data-plane
-execution of the BMF plan with the GF(256) Pallas kernels.
+execution of the BMF plan through `repro.kernels.ops` (the GF(256) Pallas
+kernels on TPU, their numpy references elsewhere).
 
     PYTHONPATH=src python examples/repair_demo.py
 """
@@ -45,7 +46,7 @@ def main():
     print(f"\nBMFRepair vs PPR: {100 * (1 - bmf.total_time / ppr.total_time):.1f}% "
           f"faster (paper: ~15.9% avg on Aliyun)")
 
-    print("\n== executing the BMF plan on real data (GF(256) kernels) ==")
+    print("\n== executing the BMF plan on real data (GF(256) data plane) ==")
     rng = np.random.default_rng(0)
     data = rng.integers(0, 256, size=(3, 1 << 16), dtype=np.uint8)
     cw = code.encode(data)

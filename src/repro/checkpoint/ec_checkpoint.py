@@ -5,9 +5,10 @@ Layout on disk:
   <dir>/step_<N>/domain_<d>.bin         every block placed on failure domain d
 
 The flattened train-state blob is split into stripes of k chunk-sized data
-blocks; n-k parity blocks per stripe come from the `gf256_matmul` Pallas
-kernel (all stripes in one batched call). Blocks are placed RAID-5-rotated
-across `num_domains` failure domains (hosts or pods).
+blocks; n-k parity blocks per stripe come from `ops.rs_encode` (all
+stripes in one batched call: the GF(256) scale and fold kernels on TPU).
+Blocks are placed RAID-5-rotated across `num_domains` failure domains
+(hosts or pods).
 
 Losing up to n-k domains is repaired *in place*: the repair planner
 (msrepair+bmf by default — the paper's algorithms; any baseline scheme can

@@ -1,9 +1,8 @@
 """Where JAX keeps its persistent compilation cache.
 
-Called by entry points (`chip_smoke.py`, `benchmarks/run.py`,
-`benchmarks/bench_dataplane.py`) before their first compile, never at
-import: a library that picked a cache directory on import would decide
-for every program that imports it.
+Called by entry points (`chip_smoke.py`, `benchmarks/run.py`) before
+their first compile, never at import: a library that picked a cache
+directory on import would decide for every program that imports it.
 """
 from __future__ import annotations
 

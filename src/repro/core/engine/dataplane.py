@@ -23,13 +23,12 @@ On TPU the two ops run Pallas kernels over a grid (one `pallas_call`
 per step instead of one per chunk): the premultiply scales the staged
 bytes as they are, by shift-and-add over GF(256)'s polynomial on uint32
 words (`kernels.gf256_scale`, no bit planes), and the fold XORs words;
-everywhere else they fall back to the numpy oracles in
-`repro.kernels.ref`, so the batched path stays a genuine throughput win
-on CPU too (`benchmarks/bench_dataplane.py` gates it). The store
-follows the premultiply's answer: a device array on the kernel path,
-where the round state stays on the device and only the rebuilt blocks
-come back in one copy, and a host array, updated in place, on the ref
-path. The host keeps only the index plan and the rows' occupancy.
+everywhere else `kernels.ops` runs the numpy references in
+`repro.kernels.ref` instead. The store follows the premultiply's
+answer: a device array on the kernel path, where the round state stays
+on the device and only the rebuilt blocks come back in one copy, and a
+host array, updated in place, on the ref path. The host keeps only the
+index plan and the rows' occupancy.
 
 Execution semantics match the serial oracle exactly: within a round all
 sources are consumed before any arrival lands (store-and-forward
@@ -141,8 +140,6 @@ def execute_plans_batch(
     codewords: np.ndarray | Sequence[np.ndarray],
     *,
     block_of: Sequence[np.ndarray | None] | None = None,
-    use_kernel: bool | None = None,
-    interpret: bool | None = None,
 ) -> BatchExecutionResult:
     """Execute a batch of repair plans over real bytes and verify them.
 
@@ -150,16 +147,15 @@ def execute_plans_batch(
     `codes` one shared or per-case `RSCode`, `codewords` per-case
     `(n, nbytes)` uint8 block stacks (block-indexed; same nbytes across
     the batch). `block_of[b][node]` maps node ids to block positions
-    (identity when None — the simulator convention). `use_kernel=None`
-    compiles the Pallas kernels on TPU and runs the numpy ref path
-    elsewhere (see `kernels.ops`). Returns per-case reconstructed bytes,
-    a verified flag (every job's requestor buffer equals the lost block
-    bit-for-bit) and relay-aware `bytes_moved` — byte-identical to
-    running `executor.execute_plan` case by case.
+    (identity when None — the simulator convention). The GF(256) steps
+    run what `kernels.ops` chooses for the platform: the compiled Pallas
+    kernels on TPU, the numpy references elsewhere. Returns per-case
+    reconstructed bytes, a verified flag (every job's requestor buffer
+    equals the lost block bit-for-bit) and relay-aware `bytes_moved` —
+    byte-identical to running `executor.execute_plan` case by case.
     """
     with spans.span("repro.dataplane.batch"):
-        return _execute_plans_batch(plans, codes, codewords, block_of,
-                                    use_kernel, interpret)
+        return _execute_plans_batch(plans, codes, codewords, block_of)
 
 
 def _pull(x) -> np.ndarray:
@@ -244,8 +240,8 @@ def _fold_in(store, src: np.ndarray, dst: np.ndarray, folded):
     return store
 
 
-def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
-                         interpret) -> BatchExecutionResult:
+def _execute_plans_batch(plans, codes, codewords,
+                         block_of) -> BatchExecutionResult:
     """`execute_plans_batch`'s body, one span per step. A helper, so that
     the caller's batch span also holds the frees of its buffers."""
     with spans.span("repro.dataplane.prepare"):
@@ -328,8 +324,7 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
     if tdata:
         with spans.span("repro.dataplane.premultiply"):
             pre = ops.gf256_scale_batch(
-                np.asarray(tcoef, dtype=np.uint8), staged,
-                use_kernel=use_kernel, interpret=interpret)
+                np.asarray(tcoef, dtype=np.uint8), staged)
         del staged              # the staging copy must not outlive the call
     with spans.span("repro.dataplane.scatter"):
         store = _grow(pre, store_keys.size)
@@ -370,9 +365,7 @@ def _execute_plans_batch(plans, codes, codewords, block_of, use_kernel,
                 pos = np.arange(order.size) - np.repeat(starts, counts)
                 groups[np.repeat(np.arange(starts.size), counts), pos] = order
             with spans.span("repro.dataplane.fold"):
-                folded = ops.xor_reduce_segments(
-                    payload, groups, use_kernel=use_kernel,
-                    interpret=interpret)
+                folded = ops.xor_reduce_segments(payload, groups)
             with spans.span("repro.dataplane.accumulate"):
                 gdst = sdst[starts]
                 store = _fold_in(store, src, gdst, folded)

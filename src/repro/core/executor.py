@@ -4,19 +4,22 @@ The simulator times a plan; this module *runs* it. Two paths share the
 semantics:
 
 * `execute_plan` — the serial oracle below: every helper holds a real
-  chunk, premultiplies its Galois coefficient with the Pallas
-  `gf256_matmul` kernel, transfers move buffers between per-node stores,
-  and merges XOR with the `xor_reduce` kernel. Relay nodes only buffer
-  (the paper: forwarding nodes do not compute). At the end the
-  requestor's buffer must equal the lost block bit-for-bit.
+  chunk, scaled by its Galois coefficient (`ops.gf256_scale_batch`, one
+  call per job), transfers move buffers between per-node stores, and
+  merges XOR (`ops.xor_reduce_segments`). Relay nodes only buffer (the
+  paper: forwarding nodes do not compute). At the end the requestor's
+  buffer must equal the lost block bit-for-bit.
 * `execute_plans_batch` (re-exported from
   `repro.core.engine.dataplane`) — the batched engine: a whole batch of
-  compiled `PlanArrays` lowered to dense `(B, slots, nbytes)` buffer
-  tensors, all rounds executed as gather → GF(256)-premultiply →
-  segment-XOR array steps through the batched kernel entry points in
-  `repro.kernels.ops`. Byte-identical to running the oracle case by
-  case (`tests/test_dataplane.py` pins it); the oracle stays the
+  compiled `PlanArrays` in one compact `(U, nbytes)` store, a row per
+  (case, slot) key, with all rounds executed as gather → GF(256)
+  premultiply → segment-XOR array steps through the same two entry
+  points of `repro.kernels.ops`. Byte-identical to running the oracle
+  case by case (`tests/test_dataplane.py` pins it); the oracle stays the
   reference this facade keeps readable.
+
+Both run what `repro.kernels.ops` chooses for the platform: the
+compiled Pallas kernels on TPU, the numpy references elsewhere.
 
 **Invariant (both paths):** plans must be `validate_plan`-clean. The
 executors implement store-and-forward faithfully — a source's buffer is
@@ -65,7 +68,6 @@ def execute_plan(
     code: RSCode,
     codeword: np.ndarray,                  # (n, nbytes) original stripe
     *,
-    use_kernel: bool = True,
     block_of: np.ndarray | None = None,
 ) -> ExecutionResult:
     """Serial oracle: walk one validated plan over real bytes.
@@ -95,13 +97,10 @@ def execute_plan(
             tuple([int(block_of[job.failed_node])]),
             tuple(int(block_of[h]) for h in job.helpers),
         )[0]  # (k,) coefficients, aligned with job.helpers
-        for h, c in zip(job.helpers, coeffs):
-            block = jnp.asarray(codeword[block_of[h]])
-            pre = ops.gf256_matmul(
-                np.array([[c]], dtype=np.uint8), block[None, :],
-                use_kernel=use_kernel,
-            )[0]
-            store[(job.job_id, h)] = pre
+        pre = ops.gf256_scale_batch(
+            coeffs, codeword[block_of[list(job.helpers)]])
+        for h, row in zip(job.helpers, pre):
+            store[(job.job_id, h)] = row
 
     bytes_moved = 0
     for ri, rnd in enumerate(plan.rounds):
@@ -124,9 +123,8 @@ def execute_plan(
             if existing is None:
                 store[(job_id, dst)] = payload
             else:
-                store[(job_id, dst)] = ops.xor_reduce(
-                    jnp.stack([existing, payload]), use_kernel=use_kernel
-                )
+                store[(job_id, dst)] = ops.xor_reduce_segments(
+                    jnp.stack([existing, payload]), np.array([[0, 1]]))[0]
 
     recon: dict[int, np.ndarray] = {}
     ok = True

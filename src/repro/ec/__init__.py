@@ -1,3 +1,3 @@
-"""Erasure-coding substrate: GF(256) arithmetic, RS codes, bit-plane layout."""
+"""Erasure-coding substrate: GF(256) arithmetic, RS codes, stripe placement."""
 
-from repro.ec import bitplane, gf256, rs, stripe  # noqa: F401
+from repro.ec import gf256, rs, stripe  # noqa: F401

@@ -1,17 +1,20 @@
-"""Pure-jnp oracles for the Pallas kernels.
+"""Oracles for the GF(256) kernels of `repro.kernels.ops`.
 
-Two *independent* formulations:
-  * byte domain — log/exp (dense MUL_TABLE) Galois multiply + XOR accumulate,
-  * plane domain — the same bit-matrix math as the kernel but in plain jnp.
-Tests cross-check kernel vs both, and both vs the numpy peasant-multiply
-ground truth in repro/ec/gf256.py.
+* `gf256_scale_batch_np` and `xor_reduce_segments_np` — the numpy
+  references `ops` runs off TPU in place of the two Pallas kernels;
+* `gf256_matmul_bytes_ref` — an independent byte-domain formulation in
+  jnp (one 256-entry `MUL_TABLE` row per coefficient, XOR-accumulated),
+  which the tests hold next to `ops.gf256_matmul`.
+
+Tests cross-check all of them against the numpy peasant-multiply ground
+truth in repro/ec/gf256.py.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ec import bitplane, gf256
+from repro.ec import gf256
 
 
 def gf256_matmul_bytes_ref(coeff: np.ndarray, data: jnp.ndarray) -> jnp.ndarray:
@@ -40,29 +43,6 @@ def gf256_matmul_bytes_ref(coeff: np.ndarray, data: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack(outs)
 
 
-def gf256_matmul_planes_ref(masks: jnp.ndarray, planes: jnp.ndarray) -> jnp.ndarray:
-    """Plane-domain oracle, vectorized einsum-of-XOR formulation."""
-    # out[o, bi, w] = XOR_{i, bj} planes[i, bj, w] & masks[o, i, bi, bj]
-    m = masks.shape[0]
-    k = planes.shape[0]
-    outs = []
-    for o in range(m):
-        acc = jnp.zeros((8, planes.shape[-1]), dtype=jnp.uint32)
-        for i in range(k):
-            for bj in range(8):
-                acc = acc ^ (planes[i, bj][None, :] & masks[o, i, :, bj][:, None])
-        outs.append(acc)
-    return jnp.stack(outs)
-
-
-def xor_reduce_ref(words: jnp.ndarray) -> jnp.ndarray:
-    """(k, W) uint32 -> (W,) uint32."""
-    out = words[0]
-    for i in range(1, words.shape[0]):
-        out = out ^ words[i]
-    return out
-
-
 def gf256_scale_batch_np(coeffs: np.ndarray, data: np.ndarray) -> np.ndarray:
     """(M,) uint8 coeffs x (M, nbytes) uint8 -> (M, nbytes): per-row scale.
 
@@ -86,12 +66,3 @@ def xor_reduce_segments_np(chunks: np.ndarray, groups: np.ndarray) -> np.ndarray
     rows = chunks[np.maximum(groups, 0)]          # (G, K, nbytes) copy
     rows[groups < 0] = 0
     return np.bitwise_xor.reduce(rows, axis=1)
-
-
-def gf256_matmul_np(coeff: np.ndarray, data: np.ndarray) -> np.ndarray:
-    """Numpy ground truth (table-based; see gf256.gf_matmul_np)."""
-    return gf256.gf_matmul_np(coeff, data)
-
-
-def bitplane_roundtrip_np(data: np.ndarray) -> np.ndarray:
-    return bitplane.unpack_np(bitplane.pack_np(data), data.shape[-1])

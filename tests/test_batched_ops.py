@@ -11,9 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import interpreted
 from repro.ec import gf256
 from repro.ec.rs import RSCode
-from repro.kernels import gf256_scale, ops
+from repro.kernels import gf256_scale, ops, ref, xor_reduce
 
 
 # ------------------------------------------------------- gf256_scale_batch
@@ -24,17 +25,15 @@ def test_gf256_scale_batch_paths(m, nbytes, rng):
     coeffs = rng.integers(0, 256, size=m, dtype=np.uint8)
     data = rng.integers(0, 256, size=(m, nbytes), dtype=np.uint8)
     want = np.stack([gf256.MUL_TABLE[coeffs[i], data[i]] for i in range(m)])
-    got_ref = np.asarray(ops.gf256_scale_batch(coeffs, data,
-                                               use_kernel=False))
-    got_kernel = np.asarray(ops.gf256_scale_batch(
-        coeffs, data, use_kernel=True, interpret=True))
+    got_ref = np.asarray(ops.gf256_scale_batch(coeffs, data))
+    with interpreted():
+        got_kernel = np.asarray(ops.gf256_scale_batch(coeffs, data))
     assert np.array_equal(got_ref, want)
     assert np.array_equal(got_kernel, want)
     # also matches m calls of the (m, k) matmul entry point
     per_row = np.concatenate([
         np.asarray(ops.gf256_matmul(coeffs[i: i + 1, None],
-                                    jnp.asarray(data[i: i + 1]),
-                                    use_kernel=False))
+                                    jnp.asarray(data[i: i + 1])))
         for i in range(m)
     ])
     assert np.array_equal(per_row, want)
@@ -42,7 +41,8 @@ def test_gf256_scale_batch_paths(m, nbytes, rng):
 
 @pytest.mark.parametrize("nbytes", [1, 33, 1000, 4100])
 @pytest.mark.parametrize("m", [1, 256])
-def test_gf256_scale_batch_kernel_byte_domain(m, nbytes, rng):
+def test_gf256_scale_batch_kernel_byte_domain(m, nbytes, rng,
+                                              interpreted_kernels):
     """The kernel path (interpret) scales packed bytes by shift-and-add:
     every coefficient against `MUL_TABLE` in one call when M = 256, at
     widths that are no multiple of a word, a lane or a block, and as one
@@ -51,8 +51,7 @@ def test_gf256_scale_batch_kernel_byte_domain(m, nbytes, rng):
     coeffs = (np.arange(256, dtype=np.uint8) if m == 256
               else rng.integers(0, 256, size=m, dtype=np.uint8))
     data = rng.integers(0, 256, size=(m, nbytes), dtype=np.uint8)
-    got = ops.gf256_scale_batch(coeffs, data, use_kernel=True,
-                                interpret=True)
+    got = ops.gf256_scale_batch(coeffs, data)
     assert isinstance(got, jax.Array)
     assert got.shape == (m, nbytes) and got.dtype == jnp.uint8
     assert np.array_equal(np.asarray(got),
@@ -75,26 +74,62 @@ def test_gf256_scale_rows_across_blocks(m, nbytes, rng):
 def test_gf256_scale_batch_zero_and_one_coeffs(rng):
     data = rng.integers(0, 256, size=(3, 64), dtype=np.uint8)
     coeffs = np.array([0, 1, 255], dtype=np.uint8)
-    out = np.asarray(ops.gf256_scale_batch(coeffs, data, use_kernel=False))
+    out = np.asarray(ops.gf256_scale_batch(coeffs, data))
     assert not out[0].any()
     assert np.array_equal(out[1], data[1])
 
 
-@pytest.mark.parametrize("kwargs", [
-    dict(use_kernel=False), dict(interpret=True),
-    dict(use_kernel=True, interpret=True)])
-@pytest.mark.parametrize("entry", ["gf256_scale_batch", "xor_reduce_segments"])
-def test_batched_entry_points_refuse_ref_and_interpret_on_tpu(
-        entry, kwargs, monkeypatch, rng):
-    """On TPU the batched entry points run the compiled kernels only: a
-    request for the numpy oracle or the interpreter there raises instead
-    of quietly leaving the device."""
-    monkeypatch.setattr(ops, "_interpret_default", lambda: False)  # "TPU"
-    data = rng.integers(0, 256, size=(2, 64), dtype=np.uint8)
-    args = {"gf256_scale_batch": (np.array([1, 2], dtype=np.uint8), data),
-            "xor_reduce_segments": (data, np.array([[0, 1]]))}[entry]
-    with pytest.raises(ValueError, match="compiled kernels only"):
-        getattr(ops, entry)(*args, **kwargs)
+# (arguments, kernels called) of each entry point of `ops`
+_CODE = RSCode(6, 3)
+_DATA = np.random.default_rng(1).integers(0, 256, size=(3, 64),
+                                          dtype=np.uint8)
+ENTRY_POINTS = {
+    "gf256_scale_batch": ((np.array([1, 2, 3], dtype=np.uint8), _DATA),
+                          {"scale"}),
+    "xor_reduce_segments": ((_DATA, np.array([[0, 1], [2, -1]])), {"fold"}),
+    "gf256_matmul": ((np.array([[1, 2, 3], [4, 5, 6]], dtype=np.uint8),
+                      _DATA), {"scale", "fold"}),
+    "rs_encode": ((_CODE.parity_coeffs(), _DATA), {"scale", "fold"}),
+    "rs_reconstruct": ((_CODE.repair_coeffs((0,), (1, 2, 3)), _DATA),
+                       {"scale", "fold"}),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+def test_entry_point_runs_compiled_kernels_on_tpu(entry, monkeypatch):
+    """Where the platform rule answers "TPU", every entry point of `ops`
+    calls its kernels compiled (`interpret=False`) and never a numpy
+    reference, and still answers with the right bytes."""
+    monkeypatch.setattr(ops, "_pallas_interpret", lambda: False)  # "TPU"
+    calls = []
+
+    def compiled(name, kernel):
+        def run(*args, interpret):
+            calls.append((name, interpret))
+            return kernel(*args, interpret=True)   # no chip here
+        return run
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("a numpy reference ran on TPU")
+
+    monkeypatch.setattr(ops, "gf256_scale_rows", compiled(
+        "scale", gf256_scale.gf256_scale_rows))
+    monkeypatch.setattr(ops, "xor_reduce_groups_words", compiled(
+        "fold", xor_reduce.xor_reduce_groups_words))
+    monkeypatch.setattr(ref, "gf256_scale_batch_np", refuse)
+    monkeypatch.setattr(ref, "xor_reduce_segments_np", refuse)
+    args, kernels = ENTRY_POINTS[entry]
+    got = getattr(ops, entry)(*args)
+    assert isinstance(got, jax.Array)
+    assert {name for name, _ in calls} == kernels
+    assert all(interpret is False for _, interpret in calls)
+    if entry == "gf256_scale_batch":
+        want = gf256.MUL_TABLE[args[0][:, None], args[1]]
+    elif entry == "xor_reduce_segments":
+        want = np.stack([_DATA[0] ^ _DATA[1], _DATA[2]])
+    else:
+        want = gf256.gf_matmul_np(*args)
+    assert np.array_equal(np.asarray(got), want)
 
 
 # ---------------------------------------------------- xor_reduce_segments
@@ -113,10 +148,9 @@ def test_xor_reduce_segments_paths(nbytes, rng):
         np.bitwise_xor.reduce(chunks[[r for r in g if r >= 0]], axis=0)
         for g in groups
     ])
-    got_ref = np.asarray(ops.xor_reduce_segments(chunks, groups,
-                                                 use_kernel=False))
-    got_kernel = np.asarray(ops.xor_reduce_segments(
-        chunks, groups, use_kernel=True, interpret=True))
+    got_ref = np.asarray(ops.xor_reduce_segments(chunks, groups))
+    with interpreted():
+        got_kernel = np.asarray(ops.xor_reduce_segments(chunks, groups))
     assert np.array_equal(got_ref, want)
     assert np.array_equal(got_kernel, want)
 

@@ -1,8 +1,11 @@
 """Batched byte data plane: parity with the serial oracle, stripe
 placements, PPT lowering, and the plan-relabeling transform."""
+import contextlib
+
 import numpy as np
 import pytest
 
+from conftest import interpreted
 from repro.core import executor, topology
 from repro.core.bandwidth import BandwidthProcess, IngressModel
 from repro.core.engine.arrays import (compile_plan, decompile,
@@ -34,11 +37,10 @@ def _plan_for(sc, scheme, seed):
 
 
 def _exec_both(plan, code, cw, block_of=None):
-    ser = executor.execute_plan(plan, code, cw, use_kernel=False,
-                                block_of=block_of)
+    ser = executor.execute_plan(plan, code, cw, block_of=block_of)
     bat = execute_plans_batch([plan], [code], [cw],
                               block_of=None if block_of is None
-                              else [block_of], use_kernel=False)
+                              else [block_of])
     return ser, bat
 
 
@@ -85,12 +87,11 @@ def test_mixed_batch_matches_serial_case_for_case(rng):
         cw = code.encode(rng.integers(0, 256, size=(k, 256), dtype=np.uint8))
         sc = _scenario(n, k, failed, seed=20 + i, cluster=cluster)
         plan = _plan_for(sc, scheme, 20 + i)
-        serials.append(executor.execute_plan(plan, code, cw,
-                                             use_kernel=False))
+        serials.append(executor.execute_plan(plan, code, cw))
         plans.append(compile_plan(plan))
         codes.append(code)
         cws.append(cw)
-    bat = execute_plans_batch(plans, codes, cws, use_kernel=False)
+    bat = execute_plans_batch(plans, codes, cws)
     assert bat.all_verified
     for b, ser in enumerate(serials):
         assert ser.verified
@@ -110,8 +111,9 @@ def test_kernel_interpret_path_matches_ref(rng):
             rng.integers(0, 256, size=(3, 200), dtype=np.uint8)))
         sc = _scenario(6, 3, (i % 6,), seed=i, cluster=10)
         plans.append(compile_plan(_plan_for(sc, "ppr", i)))
-    ref = execute_plans_batch(plans, code, cws, use_kernel=False)
-    ker = execute_plans_batch(plans, code, cws, use_kernel=True)
+    ref = execute_plans_batch(plans, code, cws)
+    with interpreted():
+        ker = execute_plans_batch(plans, code, cws)
     assert ref.all_verified and ker.all_verified
     for b in range(3):
         for jid in ref.reconstructed[b]:
@@ -140,15 +142,14 @@ def test_kernel_path_byte_identical(scheme, placed, rng):
         plans, bmaps = [plan] * 3, None
     cws = [code.encode(rng.integers(0, 256, size=(k, 200), dtype=np.uint8))
            for _ in plans]
-    ref = execute_plans_batch(plans, code, cws, block_of=bmaps,
-                              use_kernel=False)
-    ker = execute_plans_batch(plans, code, cws, block_of=bmaps,
-                              use_kernel=True)
+    ref = execute_plans_batch(plans, code, cws, block_of=bmaps)
+    with interpreted():
+        ker = execute_plans_batch(plans, code, cws, block_of=bmaps)
     assert ref.all_verified and ker.all_verified
     assert np.array_equal(ref.bytes_moved, ker.bytes_moved)
     for c, pa in enumerate(plans):
         ser = executor.execute_plan(
-            decompile(pa), code, cws[c], use_kernel=False,
+            decompile(pa), code, cws[c],
             block_of=None if bmaps is None else bmaps[c])
         assert ser.verified and int(ker.bytes_moved[c]) == ser.bytes_moved
         assert (ker.reconstructed[c].keys() == ref.reconstructed[c].keys()
@@ -230,12 +231,11 @@ def test_placed_stripe_execution(rng):
         pa = relabel_plan_nodes(plan, stripe.perm(cluster))
         bmap = stripe.block_map(cluster)
         serials.append(executor.execute_plan(
-            decompile(pa), code, cw, use_kernel=False, block_of=bmap))
+            decompile(pa), code, cw, block_of=bmap))
         plans.append(pa)
         cws.append(cw)
         bmaps.append(bmap)
-    bat = execute_plans_batch(plans, code, cws, block_of=bmaps,
-                              use_kernel=False)
+    bat = execute_plans_batch(plans, code, cws, block_of=bmaps)
     assert bat.all_verified
     for b, (stripe, ser) in enumerate(zip(stripes, serials)):
         assert ser.verified
@@ -284,10 +284,11 @@ def test_batched_consumed_source_raises(rng):
     code = RSCode(4, 2)
     cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
     with pytest.raises(ValueError, match="holds no buffer"):
-        execute_plans_batch([bad], [code], [cw], use_kernel=False)
+        execute_plans_batch([bad], [code], [cw])
 
 
-def test_batched_consumed_source_raises_on_kernel_path(rng):
+def test_batched_consumed_source_raises_on_kernel_path(rng,
+                                                      interpreted_kernels):
     """The same refusal where the round state lives on the device: the
     source check runs on the host's occupancy before any gather."""
     jobs = [Job(job_id=0, failed_node=0, requestor=0, helpers=(1, 2))]
@@ -301,11 +302,12 @@ def test_batched_consumed_source_raises_on_kernel_path(rng):
     cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
     with pytest.raises(ValueError, match=r"round 1: case 0 transfer sources "
                        r"slot \(job 0, node 1\) which holds no buffer"):
-        execute_plans_batch([bad], [code], [cw], use_kernel=True)
+        execute_plans_batch([bad], [code], [cw])
 
 
-@pytest.mark.parametrize("use_kernel", (False, True), ids=("ref", "kernel"))
-def test_consumed_buffer_refilled_later(use_kernel, rng):
+@pytest.mark.parametrize("path", (contextlib.nullcontext, interpreted),
+                         ids=("ref", "kernel"))
+def test_consumed_buffer_refilled_later(path, rng):
     """A node that sends its buffer and receives again in a later round
     starts from an empty buffer: the consume must clear the old bytes on
     both paths, or the requestor ends with the wrong block."""
@@ -321,8 +323,9 @@ def test_consumed_buffer_refilled_later(use_kernel, rng):
     validate_plan(plan)
     code = RSCode(4, 2)
     cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
-    ser = executor.execute_plan(plan, code, cw, use_kernel=False)
-    bat = execute_plans_batch([plan], [code], [cw], use_kernel=use_kernel)
+    ser = executor.execute_plan(plan, code, cw)
+    with path():
+        bat = execute_plans_batch([plan], [code], [cw])
     assert ser.verified and bat.all_verified
     assert np.array_equal(bat.reconstructed[0][0], cw[0])
 
@@ -337,7 +340,7 @@ def test_batched_incomplete_plan_not_verified(rng):
     ])
     code = RSCode(4, 2)
     cw = code.encode(rng.integers(0, 256, size=(2, 64), dtype=np.uint8))
-    res = execute_plans_batch([partial], [code], [cw], use_kernel=False)
+    res = execute_plans_batch([partial], [code], [cw])
     assert not res.all_verified
 
 
@@ -356,11 +359,9 @@ def test_unplaced_block_raises_both_paths(rng):
     ])
     bad_map = np.array([-1, 1, 2, 3])      # failed node 0 unplaced
     with pytest.raises(ValueError, match="holds no block"):
-        executor.execute_plan(plan, code, cw, use_kernel=False,
-                              block_of=bad_map)
+        executor.execute_plan(plan, code, cw, block_of=bad_map)
     with pytest.raises(ValueError, match="holds no block"):
-        execute_plans_batch([plan], [code], [cw], block_of=[bad_map],
-                            use_kernel=False)
+        execute_plans_batch([plan], [code], [cw], block_of=[bad_map])
 
 
 def test_stripe_placement_accessors():

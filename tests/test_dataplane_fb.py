@@ -12,6 +12,7 @@ and each rebuilt block is compared with gfref's decode and with the
 serial `executor.execute_plan`. The batch counters of `repro.spans` are
 pinned per batch.
 """
+import contextlib
 import importlib.util
 import json
 from pathlib import Path
@@ -20,6 +21,7 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import interpreted
 from repro import spans
 from repro.core import executor
 from repro.core.engine.arrays import decompile
@@ -56,12 +58,13 @@ def fb():
     return wl, gfref
 
 
-def _run(wl, b: int, use_kernel: bool):
+def _run(wl, b: int, path=interpreted):
     idx = wl.batches[b]
-    return execute_plans_batch(
-        [wl.compiled[s] for s in idx], wl.code,
-        [wl.codewords[s] for s in idx],
-        block_of=[wl.block_maps[s] for s in idx], use_kernel=use_kernel)
+    with path():
+        return execute_plans_batch(
+            [wl.compiled[s] for s in idx], wl.code,
+            [wl.codewords[s] for s in idx],
+            block_of=[wl.block_maps[s] for s in idx])
 
 
 def _has_double(wl, b: int) -> bool:
@@ -76,19 +79,18 @@ def test_pool_is_the_deployments_mix(fb):
     assert len(wl.batches) == len(BATCHES)
 
 
-@pytest.mark.parametrize("use_kernel", (True, False),
+@pytest.mark.parametrize("path", (interpreted, contextlib.nullcontext),
                          ids=("device_store", "host_store"))
 @pytest.mark.parametrize("b", BATCHES)
-def test_batch_matches_gfref_and_serial(fb, b, use_kernel):
+def test_batch_matches_gfref_and_serial(fb, b, path):
     wl, gfref = fb
-    res = _run(wl, b, use_kernel)
+    res = _run(wl, b, path)
     assert res.all_verified
     for pos, s in enumerate(wl.batches[b]):
         plan, cw = wl.plans[s], wl.codewords[s]
         hops = sum(len(t.path) - 1 for r in plan.rounds for t in r.transfers)
         assert int(res.bytes_moved[pos]) == NBYTES * hops
         ser = executor.execute_plan(decompile(wl.compiled[s]), wl.code, cw,
-                                    use_kernel=False,
                                     block_of=wl.block_maps[s])
         assert ser.bytes_moved == int(res.bytes_moved[pos])
         jobs = {j.job_id for j in plan.jobs}
@@ -120,7 +122,7 @@ def counted(fb, tmp_path_factory):
     with jax.profiler.trace(str(tmp_path_factory.mktemp("fb"))):
         for b in BATCHES:
             before = spans.totals()
-            _run(wl, b, use_kernel=True)
+            _run(wl, b)
             after = spans.totals()
             out.append({k: after.get(n, {}).get(c, 0)
                         - before.get(n, {}).get(c, 0)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData, TraceAnnotation
 
+from conftest import interpreted
 from repro import spans
 from repro.core import executor, topology
 from repro.core.bandwidth import BandwidthProcess, IngressModel
@@ -80,8 +81,9 @@ def _batch(kind: str) -> dict:
 
 
 def _run(b: dict):
-    return execute_plans_batch(b["plans"], b["code"], b["cws"],
-                               block_of=b["block_of"], use_kernel=True)
+    with interpreted():
+        return execute_plans_batch(b["plans"], b["code"], b["cws"],
+                                   block_of=b["block_of"])
 
 
 def _traced(b: dict, tmp: str) -> types.SimpleNamespace:
@@ -219,7 +221,7 @@ def test_d2h_bytes_zero_on_numpy_path(kind, tmp_path):
     before = spans.totals()
     with jax.profiler.trace(str(tmp_path)):
         execute_plans_batch(b["plans"], b["code"], b["cws"],
-                            block_of=b["block_of"], use_kernel=False)
+                            block_of=b["block_of"])
     after = spans.totals()
     assert _delta(before, after, "repro.dataplane.d2h", "count") > 0
     assert _delta(before, after, "repro.dataplane.d2h", "bytes") == 0
@@ -298,7 +300,7 @@ def test_profiler_off_records_nothing_and_changes_nothing(traced):
         assert got.all_verified
         for c, (pa, cw) in enumerate(zip(b["plans"], b["cws"])):
             ser = executor.execute_plan(
-                decompile(pa), b["code"], cw, use_kernel=False,
+                decompile(pa), b["code"], cw,
                 block_of=None if b["block_of"] is None else b["block_of"][c])
             assert int(got.bytes_moved[c]) == ser.bytes_moved
             assert got.reconstructed[c].keys() == ser.reconstructed.keys()

@@ -66,7 +66,7 @@ def test_consumed_source_raises_clear_error(rng):
                                   terms=frozenset({1}))]),
     ])
     with pytest.raises(ValueError, match="holds no buffer"):
-        executor.execute_plan(bad, code, cw, use_kernel=False)
+        executor.execute_plan(bad, code, cw)
     # validate_plan rejects the same plan up front — the executor
     # invariant is exactly "validate_plan-clean"
     from repro.core.plan import validate_plan
@@ -90,7 +90,7 @@ def test_source_refilled_across_rounds_is_fine(rng):
                                   terms=frozenset({1, 2}))]),
     ])
     validate_plan(plan)
-    ex = executor.execute_plan(plan, code, cw, use_kernel=False)
+    ex = executor.execute_plan(plan, code, cw)
     assert ex.verified
     assert ex.bytes_moved == 2 * 64
 
@@ -114,12 +114,12 @@ def test_bytes_moved_relay_accounting(rng):
         ]),
     ])
     validate_plan(plan, max_recv_per_round=2)
-    ex = executor.execute_plan(plan, code, cw, use_kernel=False)
+    ex = executor.execute_plan(plan, code, cw)
     assert ex.verified
     assert ex.bytes_moved == nbytes * (1 + 3)
     from repro.core.engine.dataplane import execute_plans_batch
 
-    bat = execute_plans_batch([plan], [code], [cw], use_kernel=False)
+    bat = execute_plans_batch([plan], [code], [cw])
     assert int(bat.bytes_moved[0]) == ex.bytes_moved
 
 
@@ -140,8 +140,7 @@ def test_execute_plan_block_of_placement(rng):
     ])
     block_of = np.full(13, -1, dtype=np.int64)
     block_of[[10, 11, 12]] = [0, 1, 2]
-    ex = executor.execute_plan(plan, code, cw, use_kernel=False,
-                               block_of=block_of)
+    ex = executor.execute_plan(plan, code, cw, block_of=block_of)
     assert ex.verified
     assert np.array_equal(ex.reconstructed[0], cw[0])
 

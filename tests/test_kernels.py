@@ -1,72 +1,47 @@
-"""Pallas kernel sweeps (interpret mode) vs the pure-jnp/numpy oracles."""
+"""GF(256) entry points of `repro.kernels.ops` on both of their paths —
+the numpy references and the Pallas kernels (interpret mode) — vs the
+pure-jnp/numpy oracles."""
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
 
-from repro.ec import bitplane, gf256
+from conftest import interpreted
+from repro.ec import gf256
 from repro.kernels import ops, ref
-from repro.kernels.gf256_matmul import gf256_matmul_planes
-from repro.kernels.xor_reduce import xor_reduce_words
+
+PATHS = {"ref": contextlib.nullcontext, "kernel": interpreted}
 
 
+@pytest.mark.parametrize("path", sorted(PATHS))
 @pytest.mark.parametrize("m,k", [(1, 2), (2, 3), (3, 4), (2, 6), (4, 8), (1, 16)])
 @pytest.mark.parametrize("nbytes", [32, 100, 1024, 4096])
-def test_gf256_matmul_sweep(m, k, nbytes, rng):
+def test_gf256_matmul_sweep(m, k, nbytes, path, rng):
     coeff = rng.integers(0, 256, size=(m, k), dtype=np.uint8)
     data = rng.integers(0, 256, size=(k, nbytes), dtype=np.uint8)
     want = gf256.gf_matmul_np(coeff, data)
-    got = np.asarray(ops.gf256_matmul(coeff, jnp.asarray(data)))
+    with PATHS[path]():
+        got = np.asarray(ops.gf256_matmul(coeff, jnp.asarray(data)))
     assert np.array_equal(got, want)
     # independent byte-domain oracle agrees too
     got_ref = np.asarray(ref.gf256_matmul_bytes_ref(coeff, jnp.asarray(data)))
     assert np.array_equal(got_ref, want)
 
 
-@pytest.mark.parametrize("block_w", [128, 512, 1024])
-def test_gf256_matmul_block_widths(block_w, rng):
-    coeff = rng.integers(0, 256, size=(2, 3), dtype=np.uint8)
-    data = rng.integers(0, 256, size=(3, 3000), dtype=np.uint8)
-    masks = jnp.asarray(bitplane.coeff_to_masks_np(coeff))
-    planes = bitplane.pack_jnp(jnp.asarray(data))
-    out = gf256_matmul_planes(masks, planes, block_w=block_w, interpret=True)
-    want = ref.gf256_matmul_planes_ref(masks, planes)
-    assert np.array_equal(np.asarray(out), np.asarray(want))
-
-
 @pytest.mark.parametrize("k", [2, 3, 5, 9])
 @pytest.mark.parametrize("nbytes", [4, 64, 999, 2048])
-def test_xor_reduce_sweep(k, nbytes, rng):
+def test_xor_reduce_sweep(k, nbytes, rng, interpreted_kernels):
     x = rng.integers(0, 256, size=(k, nbytes), dtype=np.uint8)
     want = x[0].copy()
     for i in range(1, k):
         want ^= x[i]
-    got = np.asarray(ops.xor_reduce(jnp.asarray(x)))
+    got = np.asarray(ops.xor_reduce_segments(jnp.asarray(x),
+                                             np.arange(k)[None])[0])
     assert np.array_equal(got, want)
 
 
-def test_xor_reduce_words_direct(rng):
-    w = rng.integers(0, 2**32, size=(4, 700), dtype=np.uint32)
-    got = np.asarray(xor_reduce_words(jnp.asarray(w), interpret=True))
-    want = w[0] ^ w[1] ^ w[2] ^ w[3]
-    assert np.array_equal(got, want)
-
-
-@given(st.integers(1, 512), st.integers(0, 2**16))
-@settings(max_examples=25, deadline=None)
-def test_bitplane_roundtrip(nbytes, seed):
-    rng = np.random.default_rng(seed)
-    data = rng.integers(0, 256, size=(2, nbytes), dtype=np.uint8)
-    planes = bitplane.pack_np(data)
-    assert np.array_equal(bitplane.unpack_np(planes, nbytes), data)
-    planes_j = bitplane.pack_jnp(jnp.asarray(data))
-    assert np.array_equal(np.asarray(planes_j), planes)
-    back = bitplane.unpack_jnp(planes_j, nbytes)
-    assert np.array_equal(np.asarray(back), data)
-
-
-def test_rs_encode_reconstruct_via_kernels(rng):
+def test_rs_encode_reconstruct_via_kernels(rng, interpreted_kernels):
     from repro.ec.rs import RSCode
     for (n, k) in [(4, 2), (6, 3), (7, 4), (6, 4)]:
         code = RSCode(n, k)

@@ -18,12 +18,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.engine import dataplane
-from repro.kernels.gf256_matmul import gf256_matmul_planes
 from repro.kernels.gf256_scale import gf256_scale_rows
-from repro.kernels.xor_reduce import xor_reduce_groups_words, xor_reduce_words
+from repro.kernels.xor_reduce import xor_reduce_groups_words
 
 CELL = 1 << 20                 # HDFS RS-6-3-1024k cell, bytes
-PLANE_W = CELL // 32           # uint32 words per bit-plane of one cell
 WORDS = CELL // 4              # uint32 words of one cell
 
 
@@ -58,17 +56,13 @@ def no_compile_cache():
 # (kernel, argument shapes and dtypes, rows of the premultiply or None):
 # the premultiply of one `hdfs-rs-6-3-1024k.repair` batch (48 helper
 # chunks of 1 MiB) and of the `fb-warehouse-rs-14-10.repair` batch with
-# its double failure (90), then a 64-stripe RS(6,3) batch of 1 MiB
-# cells — a 3x3 parity encode, a 4-way fold and 64 three-member fan-in
-# groups
+# its double failure (90), then the fold of a 64-stripe RS(6,3) batch
+# of 1 MiB cells: 64 three-member fan-in groups
 KERNELS = {
     "gf256_scale_rows_hdfs": (gf256_scale_rows, [
         ((48,), jnp.uint8), ((48, CELL), jnp.uint8)], 48),
     "gf256_scale_rows_fb": (gf256_scale_rows, [
         ((90,), jnp.uint8), ((90, CELL), jnp.uint8)], 90),
-    "gf256_matmul_planes": (gf256_matmul_planes, [
-        ((3, 3, 8, 8), jnp.uint32), ((3, 8, PLANE_W), jnp.uint32)], None),
-    "xor_reduce_words": (xor_reduce_words, [((4, WORDS), jnp.uint32)], None),
     "xor_reduce_groups_words": (xor_reduce_groups_words, [
         ((64, 3, WORDS), jnp.uint32)], None),
 }
