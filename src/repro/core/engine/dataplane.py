@@ -8,8 +8,11 @@ compiled plans into one compact `(U, nbytes)` store — a row per (case,
 slot) key the batch touches; slot `j * N + v` is node v's buffer for job
 j — and executes every round as three array steps:
 
-1. **GF(256) premultiply** (init round only) — every helper chunk scaled
-   by its repair coefficient in one `kernels.ops.gf256_scale_batch` call,
+1. **GF(256) premultiply** (init round only) — every helper chunk,
+   handed to `kernels.ops.stage_rows` as the row of the caller's codeword
+   where it lies (on the kernel path it goes up from there, with no host
+   copy, and the device stacks the batch), scaled by its repair
+   coefficient in one `kernels.ops.gf256_scale_batch` call,
    with the coefficients themselves computed batched by
    `RSCode.repair_coeffs_batch` (one lockstep Gauss-Jordan per code); the
    result becomes the store's first rows;
@@ -311,21 +314,26 @@ def _execute_plans_batch(plans, codes, codewords,
         occupied = np.zeros(store_keys.size, dtype=bool)
 
     # ---- init: one batched premultiply of every helper chunk
+    # views of the caller's codeword rows, no host copy: `ops.stage_rows`
+    # stacks them where the premultiply runs, and `_pull` waits for a
+    # result that depends on every row, so the caller may reuse them after
     with spans.span("repro.dataplane.stage"):
-        tcoef, tdata = [], []
+        tcoef, rows = [], []
         for b, pa in enumerate(pas):
             for j in range(pa.num_jobs):
                 hs = pa.job_helpers[j, :int(pa.job_helpers_len[j])]
                 tcoef.extend(coeffs[b][j])
-                tdata.append(cws[b][block_maps[b][hs.astype(np.int64)]])
-        staged = np.concatenate(tdata) if tdata else None
-        spans.count("helper_bytes", 0 if staged is None else staged.nbytes)
+                rows += [cws[b][blk] for blk in block_maps[b][hs]]
+        staged = ops.stage_rows(rows) if rows else None
+        spans.count("helper_bytes", len(rows) * nbytes)
+        spans.count("device_rows",
+                    len(rows) if isinstance(staged, jax.Array) else 0)
     pre = np.zeros((0, nbytes), dtype=np.uint8)
-    if tdata:
+    if staged is not None:
         with spans.span("repro.dataplane.premultiply"):
             pre = ops.gf256_scale_batch(
                 np.asarray(tcoef, dtype=np.uint8), staged)
-        del staged              # the staging copy must not outlive the call
+        del staged              # the staged chunks must not outlive the call
     with spans.span("repro.dataplane.scatter"):
         store = _grow(pre, store_keys.size)
         occupied[:len(pre)] = True

@@ -1,11 +1,12 @@
 """Public entry points for the GF(256) steps of the EC data plane.
 
-Two steps, one kernel each:
+Two steps, one kernel each, and the staging of the first's input:
 
 * `gf256_scale_batch` — every row of bytes times its own coefficient
   (`kernels.gf256_scale`, on the bytes as they are);
 * `xor_reduce_segments` — XOR-fold of groups of rows
-  (`kernels.xor_reduce`).
+  (`kernels.xor_reduce`);
+* `stage_rows` — host rows into one array where the premultiply runs.
 
 `gf256_matmul` — and `rs_encode` / `rs_reconstruct` on it — is built from
 the two: scale every (output, input) pair, then fold each output's k rows.
@@ -38,14 +39,35 @@ def _pallas_interpret() -> bool | None:
     return False if jax.default_backend() == "tpu" else None
 
 
+@jax.jit
+def _stack_rows(*rows):
+    return jnp.stack(rows)
+
+
+def stage_rows(rows):
+    """(nbytes,) uint8 host rows -> one (M, nbytes) array, where
+    `gf256_scale_batch` reads it.
+
+    The kernel path hands every row to the device as it lies in the
+    caller's memory, with no host copy, and one jitted program stacks
+    them there (one program per row count). The reference path, which
+    wants host bytes, makes one host concatenation. The rows must stay
+    unchanged until the result has been computed.
+    """
+    if _pallas_interpret() is None:
+        return np.stack(rows)
+    return _stack_rows(*rows)
+
+
 def gf256_scale_batch(coeffs: np.ndarray, data):
     """(M,) uint8 coeffs x (M, nbytes) uint8 -> (M, nbytes): row i scaled
     by its own coefficient.
 
     The batched data-plane premultiply: one call covers every
     (job, helper) chunk of a plan batch. The kernel path is one jitted
-    program, `gf256_scale_rows`: the host `data` goes up as it is and the
-    kernel scales its bytes directly, by shift-and-add over 0x11D on
+    program, `gf256_scale_rows`: `data` goes up as it is where it lives
+    on the host (`stage_rows` gives it on the device), and the kernel
+    scales its bytes directly, by shift-and-add over 0x11D on
     uint32 words, with per-row bit masks made on the device from
     `coeffs`. The reference is `ref.gf256_scale_batch_np`.
     """

@@ -1,5 +1,6 @@
 """Batched data-plane primitives: grid-driven kernel entry points
-(`kernels.ops.gf256_scale_batch` / `xor_reduce_segments`), the lockstep
+(`kernels.ops.gf256_scale_batch` / `xor_reduce_segments`), the staging
+of the premultiply's rows (`kernels.ops.stage_rows`), the lockstep
 GF(256) Gauss-Jordan, and `RSCode.repair_coeffs_batch`.
 
 Separate from tests/test_kernels.py and tests/test_rs.py on purpose:
@@ -69,6 +70,29 @@ def test_gf256_scale_rows_across_blocks(m, nbytes, rng):
     got = gf256_scale.gf256_scale_rows(coeffs, data, interpret=True)
     assert np.array_equal(np.asarray(got),
                           gf256.MUL_TABLE[coeffs[:, None], data])
+
+
+# ------------------------------------------------------------- stage_rows
+@pytest.mark.parametrize("m,nbytes", [(1, 64), (1, 7), (5, 1001), (48, 256)])
+def test_stage_rows_paths(m, nbytes, rng):
+    """Rows cut out of wider codewords (views, not copies) stage to the
+    same (M, nbytes) bytes on both paths: a host array on the numpy
+    path, a device array where the kernels run, whose premultiply then
+    reads the same bytes as it would from the host."""
+    cws = rng.integers(0, 256, size=(m, 3, nbytes), dtype=np.uint8)
+    rows = [cw[i % 3] for i, cw in enumerate(cws)]
+    want = np.stack([r.copy() for r in rows])
+    got_ref = ops.stage_rows(rows)
+    assert isinstance(got_ref, np.ndarray)
+    assert np.array_equal(got_ref, want)
+    coeffs = rng.integers(0, 256, size=m, dtype=np.uint8)
+    with interpreted():
+        got = ops.stage_rows(rows)
+        assert isinstance(got, jax.Array)
+        assert got.shape == (m, nbytes) and got.dtype == jnp.uint8
+        assert np.array_equal(np.asarray(got), want)
+        assert np.array_equal(np.asarray(ops.gf256_scale_batch(coeffs, got)),
+                              gf256.MUL_TABLE[coeffs[:, None], want])
 
 
 def test_gf256_scale_batch_zero_and_one_coeffs(rng):

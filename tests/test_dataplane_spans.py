@@ -20,7 +20,8 @@ from repro.core import executor, topology
 from repro.core.bandwidth import BandwidthProcess, IngressModel
 from repro.core.engine.arrays import (compile_plan, decompile,
                                       relabel_plan_nodes)
-from repro.core.engine.dataplane import execute_plans_batch
+from repro.core.engine.dataplane import (execute_plans_batch,
+                                        identity_block_map)
 from repro.core.simulator import Scenario
 from repro.ec.rs import RSCode
 from repro.ec.stripe import place_stripes
@@ -250,12 +251,26 @@ def test_batch_counts_jobs_rounds_and_helper_bytes(traced):
     assert got("repro.dataplane.stage", "helper_bytes") == _helper_bytes(b)
 
 
+def _helper_rows(b: dict) -> list[tuple[int, int]]:
+    """(case, block) of every helper chunk, in staging order: case by
+    case, job by job, each job's helpers in plan order."""
+    out = []
+    for c, pa in enumerate(b["plans"]):
+        bmap = (identity_block_map(pa.num_nodes, b["code"].n)
+                if b["block_of"] is None else b["block_of"][c])
+        for j in range(pa.num_jobs):
+            hs = pa.job_helpers[j, :int(pa.job_helpers_len[j])]
+            out += [(c, int(blk)) for blk in bmap[hs]]
+    return out
+
+
 @pytest.mark.parametrize("kind", KINDS)
-def test_gf_steps_get_host_chunks_and_round_payloads(kind, monkeypatch):
-    """The premultiply gets the (M, nbytes) helper chunks from host
-    memory; each fold gets exactly its round's (T_r, nbytes) payload,
-    on the device, and its (G_r, Kmax) groups: the byte counts the
-    benchmark's rooflines take from these arguments stay the work done."""
+def test_gf_steps_get_device_chunks_and_round_payloads(kind, monkeypatch):
+    """The premultiply gets the (M, nbytes) helper chunks on the device,
+    byte for byte every job's helper rows in staging order; each fold
+    gets exactly its round's (T_r, nbytes) payload, on the device, and
+    its (G_r, Kmax) groups: the byte counts the benchmark's rooflines
+    take from these arguments stay the work done."""
     b = _batch(kind)
     seen = []
 
@@ -274,8 +289,10 @@ def test_gf_steps_get_host_chunks_and_round_payloads(kind, monkeypatch):
     (pname, coeffs, chunks), *folds = seen
     helpers = sum(int(pa.job_helpers_len.sum()) for pa in b["plans"])
     assert pname == "gf256_scale_batch"
-    assert isinstance(chunks, np.ndarray)
+    assert isinstance(chunks, jax.Array)
     assert chunks.shape == (helpers, NBYTES) and coeffs.shape == (helpers,)
+    assert np.array_equal(np.asarray(chunks), np.stack(
+        [b["cws"][c][blk] for c, blk in _helper_rows(b)]))
     assert len(folds) == _rounds_run(b)
     for r, (fname, payload, groups) in enumerate(folds):
         sl = [pa.round_rows(r) if r < pa.num_rounds else slice(0, 0)
@@ -291,22 +308,75 @@ def test_gf_steps_get_host_chunks_and_round_payloads(kind, monkeypatch):
         assert sorted(groups[groups >= 0].tolist()) == list(range(t_r))
 
 
+def _serial_match(b: dict, res) -> None:
+    """`res` is byte for byte what serial `execute_plan` gives, case by
+    case, with the same bytes moved."""
+    assert res.all_verified
+    for c, (pa, cw) in enumerate(zip(b["plans"], b["cws"])):
+        ser = executor.execute_plan(
+            decompile(pa), b["code"], cw,
+            block_of=None if b["block_of"] is None else b["block_of"][c])
+        assert int(res.bytes_moved[c]) == ser.bytes_moved
+        assert res.reconstructed[c].keys() == ser.reconstructed.keys()
+        for jid, blk in ser.reconstructed.items():
+            assert np.array_equal(res.reconstructed[c][jid], np.asarray(blk))
+
+
+@pytest.mark.parametrize("kernels", (True, False), ids=("kernel", "ref"))
+@pytest.mark.parametrize("kind", KINDS)
+def test_stage_hands_over_codeword_rows_uncopied(kind, kernels, monkeypatch):
+    """Each helper chunk reaches `ops.stage_rows` as the row of the
+    caller's codeword where it lies: no host copy comes before it, on
+    either path, and the results stay byte-identical to serial
+    `execute_plan`."""
+    b = _batch(kind)
+    handed = []
+    orig = ops.stage_rows
+
+    def spy(rows):
+        handed.append(list(rows))
+        return orig(rows)
+
+    monkeypatch.setattr(ops, "stage_rows", spy)
+    res = (_run(b) if kernels else execute_plans_batch(
+        b["plans"], b["code"], b["cws"], block_of=b["block_of"]))
+    (rows,) = handed
+    want = _helper_rows(b)
+    assert len(rows) == len(want)
+    for row, (c, blk) in zip(rows, want):
+        assert np.shares_memory(row, b["cws"][c][blk])
+        assert row.shape == (NBYTES,)
+    _serial_match(b, res)
+
+
+@pytest.mark.parametrize("kernels", (True, False), ids=("kernel", "ref"))
+@pytest.mark.parametrize("kind", KINDS)
+def test_device_rows_count_chunks_staged_uncopied(kind, kernels, tmp_path):
+    """`device_rows` on the stage span is every helper chunk where the
+    kernels run, which hand the rows up as they lie, and 0 on the numpy
+    path, which stacks them on the host."""
+    b = _batch(kind)
+    before = spans.totals()
+    with jax.profiler.trace(str(tmp_path)):
+        if kernels:
+            _run(b)
+        else:
+            execute_plans_batch(b["plans"], b["code"], b["cws"],
+                                block_of=b["block_of"])
+    after = spans.totals()
+    got = _delta(before, after, "repro.dataplane.stage", "device_rows")
+    assert got == (len(_helper_rows(b)) if kernels else 0)
+    assert _delta(before, after, "repro.dataplane.stage",
+                  "helper_bytes") == _helper_bytes(b)
+
+
 def test_profiler_off_records_nothing_and_changes_nothing(traced):
     b, traced_res = traced.b, traced.res
     before = spans.totals()
     res = _run(b)
     assert spans.totals() == before
     for got in (res, traced_res):
-        assert got.all_verified
-        for c, (pa, cw) in enumerate(zip(b["plans"], b["cws"])):
-            ser = executor.execute_plan(
-                decompile(pa), b["code"], cw,
-                block_of=None if b["block_of"] is None else b["block_of"][c])
-            assert int(got.bytes_moved[c]) == ser.bytes_moved
-            assert got.reconstructed[c].keys() == ser.reconstructed.keys()
-            for jid, blk in ser.reconstructed.items():
-                assert np.array_equal(got.reconstructed[c][jid],
-                                      np.asarray(blk))
+        _serial_match(b, got)
     assert np.array_equal(res.bytes_moved, traced_res.bytes_moved)
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.engine import dataplane
+from repro.kernels import ops
 from repro.kernels.gf256_scale import gf256_scale_rows
 from repro.kernels.xor_reduce import xor_reduce_groups_words
 
@@ -109,3 +110,17 @@ def test_store_program_compiles_small_for_v5e(name, one_chip,
     assert mem.generated_code_size_in_bytes < 1 << 20
     if name == "fold_in":
         assert mem.alias_size_in_bytes == ROWS * CELL
+
+
+@pytest.mark.parametrize("rows", (48, 90))
+def test_stage_stack_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    """The stage's stack of 1 MiB rows, handed over one argument each,
+    at the premultiply's row counts above, gives the (M, 1 MiB) array the
+    premultiply reads (padded to whole tiles of 8 rows). Its scratch (the
+    rows' relayout into the 2-D tiles, 1.8x and 2.7x the result here)
+    stays under the 4x that one 4-row tile per row would take."""
+    args = [jax.ShapeDtypeStruct((CELL,), jnp.uint8, sharding=one_chip)
+            ] * rows
+    mem = ops._stack_rows.lower(*args).compile().memory_analysis()
+    assert rows * CELL <= mem.output_size_in_bytes < (rows + 8) * CELL
+    assert mem.temp_size_in_bytes < 4 * rows * CELL
